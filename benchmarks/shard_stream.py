@@ -21,9 +21,11 @@ per (device, group); the per-leaf fallback costs one per (leaf, shard);
 steady-state transfer wait is >= 2x lower; all schedules bitwise-equal to
 eager sharded placement.  Emits ``results/bench/BENCH_shard.json``.
 
-The forced device count must precede JAX init, so ``main()`` re-execs
-itself in a child process with ``XLA_FLAGS`` set (the aggregator runs
-suites in-process with JAX already initialised).
+On the CPU the forced device count must precede JAX init, so ``main()``
+re-execs itself in a child process with ``XLA_FLAGS`` set (the aggregator
+runs suites in-process with JAX already initialised).  On any other
+backend the parent holds the chip, so the suite runs in-process over
+``jax.devices()``.
 """
 from __future__ import annotations
 
@@ -40,7 +42,7 @@ N_GROUPS = 16
 N_DEVICES = 2
 
 
-def _run_child() -> list[dict]:
+def _run(n_devices: int) -> list[dict]:
     import numpy as np
 
     import jax
@@ -51,13 +53,15 @@ def _run_child() -> list[dict]:
     from repro.core.engine import EngineConfig, PAPER_EPIPHANY_LINK
     from repro.core.hoststream import HostStreamExecutor, StreamStats
     from repro.core.refspec import AUTO, PrefetchSpec
-    from repro.jaxcompat import make_mesh
 
     smoke = bool(os.environ.get("REPRO_BENCH_SMOKE"))
     repeats = 3 if smoke else 5
     devs = jax.devices()
-    assert len(devs) == N_DEVICES, devs
-    mesh = make_mesh((1, N_DEVICES), ("data", "model"))
+    assert len(devs) == n_devices, devs
+    mesh = jax.make_mesh(
+        (1, n_devices), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
+    )
 
     # small leaves: the link's per-request cost (the term coalescing
     # collapses) dominates the serial bandwidth term
@@ -139,7 +143,8 @@ def _run_child() -> list[dict]:
     )
     by["per_leaf"]["wait_collapse_vs_per_leaf"] = 1.0
     C.print_table(
-        "sharded coalesced streaming vs per-leaf fallback (2-device mesh, paper link)",
+        f"sharded coalesced streaming vs per-leaf fallback "
+        f"({n_devices}-device mesh, paper link)",
         rows,
         ["config", "n_devices", "requests_per_group", "requests_per_device_group",
          "steady_wait_per_group_s", "transfer_wait_s", "total_s",
@@ -149,12 +154,11 @@ def _run_child() -> list[dict]:
     return rows
 
 
-def _child_main() -> int:
-    rows = _run_child()
+def _gate(rows: list[dict]) -> int:
     by = {r["config"]: r for r in rows}
     eng, leaf = by["sharded"], by["per_leaf"]
     one_per_device = eng["requests_per_device_group"] == 1.0
-    storm = leaf["requests_per_group"] == (1 + N_W_PARTS) * N_DEVICES
+    storm = leaf["requests_per_group"] == (1 + N_W_PARTS) * eng["n_devices"]
     collapse = eng["wait_collapse_vs_per_leaf"]
     print(
         f"requests/group: sharded {eng['requests_per_group']:.0f} "
@@ -167,9 +171,15 @@ def _child_main() -> int:
 
 def main() -> int:
     if os.environ.get(_CHILD_FLAG):
-        return _child_main()
+        return _gate(_run(N_DEVICES))
+    import jax
+
+    if jax.default_backend() != "cpu":
+        # this process now holds the chip: a child could not get it
+        return _gate(_run(len(jax.devices())))
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={N_DEVICES}"
+    env["JAX_PLATFORMS"] = "cpu"
     env[_CHILD_FLAG] = "1"
     env.setdefault("PYTHONPATH", "src")
     proc = subprocess.run([sys.executable, "-m", "benchmarks.shard_stream"], env=env)

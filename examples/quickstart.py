@@ -53,9 +53,7 @@ print("listing-2 streamed:", np.allclose(np.asarray(out), big_a + big_b))
 # 3. Paper Listing 3 / §3.2: memory kinds — one line moves data between
 #    hierarchy levels; the kind handles the mechanics
 # ---------------------------------------------------------------------------
-from repro.jaxcompat import make_mesh
-
-mesh = make_mesh((1,), ("data",))
+mesh = jax.make_mesh((1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 x = jnp.arange(8.0)
 x_host = mk.place(x, mesh, jax.sharding.PartitionSpec(), mk.PINNED_HOST)
 x_dev = mk.place(x_host, mesh, jax.sharding.PartitionSpec(), mk.DEVICE)

@@ -31,10 +31,11 @@ This module provides:
     moments / KV cache / activations), the "one-line change moves your data"
     property of the paper — including ``DISK_OPT`` / ``DISK_PARAMS`` presets
     for the disk tier,
-  * a backend capability probe with graceful fallback: backends whose runtime
-    cannot execute host-placed buffers (the CPU runtime in this container)
-    transparently map host kinds onto device memory while keeping the program
-    topology (slice + copy + double-buffer) identical.
+  * a backend capability probe with a CPU-only fallback: the CPU runtime,
+    which cannot execute host-placed buffers, maps host kinds onto its one
+    memory while keeping the program topology (slice + copy +
+    double-buffer) identical; any other backend raises rather than put a
+    host kind in device memory.
 """
 from __future__ import annotations
 
@@ -198,18 +199,31 @@ def default_memory_kind() -> Optional[str]:
 _warned_kinds: set = set()
 
 
+def _on_cpu() -> bool:
+    """The CPU backend is the only one allowed to fold host tiers into its
+    default memory: it *is* its own host and device tier."""
+    return jax.default_backend() == "cpu"
+
+
 def backend_kind_string(jax_kind: str) -> Optional[str]:
     """Map a logical jax memory-kind string onto one this backend accepts.
 
     Backends differ in what they enumerate (TPU: ``device`` + ``pinned_host``;
-    some CPU builds: only ``unpinned_host``).  A kind the backend does not
-    enumerate maps to ``None`` — the backend default memory — which is the
-    physically correct tier on a single-memory backend (it *is* its own host
-    and device tier).  Mapping a *host* kind to the default on a multi-tier
-    backend loses the placement, so that case warns once per kind.
+    some CPU builds: only ``unpinned_host``).  On the CPU a kind the backend
+    does not enumerate maps to ``None`` — the backend default memory — which
+    is the physically correct tier there (it *is* its own host and device
+    tier); a host kind warns once.  On any other backend a host kind it
+    cannot address raises: placing it in device memory would hide the
+    hierarchy on the chip.
     """
     if jax_kind in backend_memory_kinds():
         return jax_kind
+    if jax_kind != "device" and not _on_cpu():
+        raise RuntimeError(
+            f"memory kind {jax_kind!r} is not enumerated by the "
+            f"{jax.default_backend()} backend ({backend_memory_kinds()}); "
+            "refusing to place it in device memory"
+        )
     if jax_kind != "device" and jax_kind not in _warned_kinds:
         _warned_kinds.add(jax_kind)
         import warnings
@@ -229,26 +243,29 @@ def host_offload_supported() -> bool:
 
     The CPU runtime enumerates ``pinned_host`` but lacks the
     ``annotate_device_placement`` custom-call implementation, so we probe by
-    compiling a tiny host->device copy.
+    compiling a tiny host->device copy.  Only the CPU's probe failure means
+    "unsupported"; on any other backend the probe's error propagates.
     """
     if "pinned_host" not in backend_memory_kinds():
         return False
+    import jax.numpy as jnp
+
+    dev = jax.devices()[0]
+    host_s = jax.sharding.SingleDeviceSharding(dev, memory_kind="pinned_host")
+    dev_s = jax.sharding.SingleDeviceSharding(dev, memory_kind="device")
+
+    def f(x):
+        return jax.device_put(x, dev_s) * 2.0
+
     try:
-        import jax.numpy as jnp
-
-        dev = jax.devices()[0]
-        host_s = jax.sharding.SingleDeviceSharding(dev, memory_kind="pinned_host")
-        dev_s = jax.sharding.SingleDeviceSharding(dev, memory_kind="device")
-
-        def f(x):
-            return jax.device_put(x, dev_s) * 2.0
-
         jax.jit(f, in_shardings=(host_s,), out_shardings=dev_s).lower(
             jax.ShapeDtypeStruct((8,), jnp.float32)
         ).compile()
-        return True
     except Exception:
+        if not _on_cpu():
+            raise
         return False
+    return True
 
 
 def resolve_kind(kind: "MemKind | str", *, allow_fallback: bool = True) -> MemKind:
@@ -257,10 +274,12 @@ def resolve_kind(kind: "MemKind | str", *, allow_fallback: bool = True) -> MemKi
     Kinds XLA cannot address (``DiskHost``) first resolve to their *staging*
     kind — the compiled program only ever sees the staging tier; the extra
     level is the runtime's business (spill store + transfer engine).  On
-    backends without host-offload execution support, host kinds then fall
-    back to ``Device`` (identical program topology, both tiers physically in
-    the same memory).  Lowering-only paths (the dry-run) may pass
-    ``allow_fallback=False`` to keep the true placement in the StableHLO.
+    the CPU backend, which cannot execute host-placed buffers, host kinds
+    then fall back to ``Device`` (identical program topology, both tiers
+    physically in the same memory).  On any other backend a host kind it
+    cannot execute raises instead of quietly landing in device memory.
+    Lowering-only paths (the dry-run) may pass ``allow_fallback=False`` to
+    keep the true placement in the StableHLO.
     """
     kind = as_kind(kind)
     if not kind.jax_addressable:
@@ -269,6 +288,12 @@ def resolve_kind(kind: "MemKind | str", *, allow_fallback: bool = True) -> MemKi
         return kind
     if not allow_fallback or host_offload_supported():
         return kind
+    if not _on_cpu():
+        raise RuntimeError(
+            f"memory kind {kind.jax_kind!r} cannot execute on the "
+            f"{jax.default_backend()} backend; refusing to fall back to "
+            "device memory"
+        )
     return DEVICE
 
 

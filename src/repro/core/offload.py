@@ -34,9 +34,8 @@ from typing import Any, Callable, Mapping, Optional
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec
+from jax.sharding import AxisType, Mesh, PartitionSpec
 
-from repro import jaxcompat
 from repro.core import memkind as mk
 from repro.core import prefetch as pf
 from repro.core.refspec import OffloadRef
@@ -46,7 +45,7 @@ __all__ = ["offload"]
 
 def _default_mesh() -> Mesh:
     dev = jax.devices()
-    return jaxcompat.make_mesh((len(dev),), ("data",))
+    return jax.make_mesh((len(dev),), ("data",), axis_types=(AxisType.Auto,))
 
 
 class OffloadedFunction:

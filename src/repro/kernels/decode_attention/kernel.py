@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.jaxcompat import tpu_compiler_params
-
 from repro.core.engine import static_auto_distance
 from repro.core.refspec import PrefetchSpec
 
@@ -34,7 +32,7 @@ LANES = 128
 
 
 def _decode_kernel(
-    len_ref,  # (1,) int32 SMEM — valid cache length for this (b, kh) program
+    len_ref,  # (BKH,) int32 SMEM (scalar prefetch) — valid cache lengths
     q_ref,  # (1, G, H) VMEM
     k_hbm,  # (BKH, T, H) ANY — by reference
     v_hbm,  # (BKH, T, H) ANY
@@ -52,7 +50,7 @@ def _decode_kernel(
 ):
     b = pl.program_id(0)
     g, h = q_ref.shape[1], q_ref.shape[2]
-    length = len_ref[0]
+    length = len_ref[b]
     needed = (length + block_kv - 1) // block_kv  # dynamic trip count
 
     def copy_block(i, slot):
@@ -132,6 +130,7 @@ def decode_attention_p(
     *,
     spec: PrefetchSpec,
     block_kv: int,
+    sm_scale: float,
     interpret: bool,
 ) -> jax.Array:
     bkh, g, h = q.shape
@@ -148,28 +147,32 @@ def decode_attention_p(
         n_t=n_t,
         distance=distance,
         slots=slots,
-        sm_scale=h ** -0.5,
+        sm_scale=sm_scale,
     )
-    # lengths are delivered per program via an SMEM BlockSpec.
-    return pl.pallas_call(
-        kernel,
+    # lengths ride in SMEM as a scalar-prefetch operand: the whole (BKH,)
+    # vector is resident and each program reads its own entry
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(bkh,),
         in_specs=[
-            pl.BlockSpec((1,), lambda b: (b,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, g, h), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, g, h), lambda b, lens: (b, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, g, h), lambda b: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((bkh, g, h), q.dtype),
+        out_specs=pl.BlockSpec((1, g, h), lambda b, lens: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((slots, block_kv, h), k.dtype),
             pltpu.VMEM((slots, block_kv, h), v.dtype),
             pltpu.SemaphoreType.DMA((slots,)),
             pltpu.SemaphoreType.DMA((slots,)),
         ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((bkh, g, h), q.dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
     )(lengths, q, k, v)

@@ -40,18 +40,24 @@ def decode_attention(
 
     bkv = min(block_kv, _ceil_to(t, 128))
     tp = _ceil_to(t, bkv)
+    # head_dim is zero-padded to the 128-lane tile: the kernel's DMA slabs
+    # must be lane-aligned, and the TPU's HBM layout pads a narrower minor
+    # dim to 128 anyway.  Zero lanes add nothing to q.k or to p.v.
+    hp = _ceil_to(h, 128)
 
     qg = q.reshape(b, kh, g, h).reshape(b * kh, g, h)
     kg = k.transpose(0, 2, 1, 3).reshape(b * kh, t, h)
     vg = v.transpose(0, 2, 1, 3).reshape(b * kh, t, h)
-    kg = jnp.pad(kg, ((0, 0), (0, tp - t), (0, 0)))
-    vg = jnp.pad(vg, ((0, 0), (0, tp - t), (0, 0)))
+    qg = jnp.pad(qg, ((0, 0), (0, 0), (0, hp - h)))
+    kg = jnp.pad(kg, ((0, 0), (0, tp - t), (0, hp - h)))
+    vg = jnp.pad(vg, ((0, 0), (0, tp - t), (0, hp - h)))
     lens = jnp.repeat(lengths.astype(jnp.int32), kh)
 
     out = decode_attention_p(
-        qg, kg, vg, lens, spec=spec, block_kv=bkv, interpret=interpret
+        qg, kg, vg, lens, spec=spec, block_kv=bkv, sm_scale=h ** -0.5,
+        interpret=interpret,
     )
-    return out.reshape(b, kh, g, h).reshape(b, n, h)
+    return out[..., :h].reshape(b, kh, g, h).reshape(b, n, h)
 
 
 def decode_attention_paged(

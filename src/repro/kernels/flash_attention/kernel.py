@@ -11,8 +11,11 @@ grid (we never *launch* them) for the causal lower triangle, and cheaply
 via ``pl.when`` for window-expired blocks.
 
 GQA: grid is over KV heads; the q block holds all ``G = N/KH`` query heads of
-the group, folded into the row dimension (``block_q * G`` rows), so the MXU
-matmul is dense and KV is never replicated.
+the group, head-major (``(G, block_q, H)``), folded into the row dimension
+(``G * block_q`` rows), so the MXU matmul is dense and KV is never
+replicated.  Folding the *leading* head axis into the sublane-aligned
+``block_q`` axis is a free reshape for Mosaic at any head_dim; folding a
+small trailing head axis into rows is not (refused at head_dim 64).
 """
 from __future__ import annotations
 
@@ -23,20 +26,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.jaxcompat import tpu_compiler_params
 
 NEG_INF = -1e30
 LANES = 128
 
 
 def _flash_kernel(
-    q_ref,  # (1, block_q, G, H)
-    k_ref,  # (1, block_kv, 1, H)
-    v_ref,  # (1, block_kv, 1, H)
-    o_ref,  # (1, block_q, G, H)
-    m_ref,  # (block_q * G, LANES) f32 — running max
-    l_ref,  # (block_q * G, LANES) f32 — running sum
-    acc_ref,  # (block_q * G, H) f32
+    q_ref,  # (1, G, block_q, H)
+    k_ref,  # (1, block_kv, H)
+    v_ref,  # (1, block_kv, H)
+    o_ref,  # (1, G, block_q, H)
+    m_ref,  # (G * block_q, LANES) f32 — running max
+    l_ref,  # (G * block_q, LANES) f32 — running sum
+    acc_ref,  # (G * block_q, H) f32
     *,
     causal: bool,
     window: int,
@@ -55,9 +57,9 @@ def _flash_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    g = q_ref.shape[2]
+    g = q_ref.shape[1]
     h = q_ref.shape[3]
-    rows = block_q * g
+    rows = g * block_q
 
     q_start = qi * block_q + q_offset  # absolute position of query row 0
     k_start = ki * block_kv
@@ -74,15 +76,15 @@ def _flash_kernel(
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0].reshape(rows, h)  # (bq*G, H) — group heads folded into rows
-        k = k_ref[0, :, 0, :]  # (bkv, H)
-        v = v_ref[0, :, 0, :]
+        q = q_ref[0].reshape(rows, h)  # (G*bq, H) — group heads folded into rows
+        k = k_ref[0]  # (bkv, H)
+        v = v_ref[0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (rows, bkv)
         s = s * sm_scale
 
-        qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (rows, block_kv), 0) // g
+        qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (rows, block_kv), 0) % block_q
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (rows, block_kv), 1)
         mask = jnp.ones((rows, block_kv), jnp.bool_)
         if causal:
@@ -111,13 +113,13 @@ def _flash_kernel(
         l = l_ref[:, :1]
         l = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> zero output
         out = (acc_ref[...] / l).astype(o_ref.dtype)
-        o_ref[0] = out.reshape(block_q, g, h)
+        o_ref[0] = out.reshape(g, block_q, h)
 
 
 def flash_attention_p(
-    q: jax.Array,  # (BKH, S, G, H)  — batch*kv_heads flattened, G query heads
-    k: jax.Array,  # (BKH, T, 1, H)
-    v: jax.Array,  # (BKH, T, 1, H)
+    q: jax.Array,  # (BKH, G, S, H)  — batch*kv_heads flattened, G query heads
+    k: jax.Array,  # (BKH, T, H)
+    v: jax.Array,  # (BKH, T, H)
     *,
     causal: bool,
     window: int,
@@ -126,7 +128,7 @@ def flash_attention_p(
     block_kv: int,
     interpret: bool,
 ) -> jax.Array:
-    bkh, s, g, h = q.shape
+    bkh, g, s, h = q.shape
     t = k.shape[1]
     assert s % block_q == 0 and t % block_kv == 0, (q.shape, k.shape, block_q, block_kv)
     n_kv_blocks = t // block_kv
@@ -146,19 +148,19 @@ def flash_attention_p(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, g, h), lambda b, _, i, j: (b, i, 0, 0)),
-            pl.BlockSpec((1, block_kv, 1, h), lambda b, _, i, j: (b, j, 0, 0)),
-            pl.BlockSpec((1, block_kv, 1, h), lambda b, _, i, j: (b, j, 0, 0)),
+            pl.BlockSpec((1, g, block_q, h), lambda b, _, i, j: (b, 0, i, 0)),
+            pl.BlockSpec((1, block_kv, h), lambda b, _, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_kv, h), lambda b, _, i, j: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, g, h), lambda b, _, i, j: (b, i, 0, 0)),
+        out_specs=pl.BlockSpec((1, g, block_q, h), lambda b, _, i, j: (b, 0, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q * g, LANES), jnp.float32),
-            pltpu.VMEM((block_q * g, LANES), jnp.float32),
-            pltpu.VMEM((block_q * g, h), jnp.float32),
+            pltpu.VMEM((g * block_q, LANES), jnp.float32),
+            pltpu.VMEM((g * block_q, LANES), jnp.float32),
+            pltpu.VMEM((g * block_q, h), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
     )(q, k, v)

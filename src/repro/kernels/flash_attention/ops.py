@@ -47,17 +47,18 @@ def flash_attention(
     bkv = min(block_kv, _ceil_to(t, 128))
     sp, tp = _ceil_to(s, bq), _ceil_to(t, bkv)
 
-    # Fold (B, KH) into one grid axis; q heads of each group ride with q.
-    qg = q.reshape(b, s, kh, g, h).transpose(0, 2, 1, 3, 4).reshape(b * kh, s, g, h)
-    kg = k.transpose(0, 2, 1, 3).reshape(b * kh, t, 1, h)
-    vg = v.transpose(0, 2, 1, 3).reshape(b * kh, t, 1, h)
+    # Fold (B, KH) into one grid axis; q heads of each group ride with q,
+    # head-major so the kernel folds them into rows with a free reshape.
+    qg = q.reshape(b, s, kh, g, h).transpose(0, 2, 3, 1, 4).reshape(b * kh, g, s, h)
+    kg = k.transpose(0, 2, 1, 3).reshape(b * kh, t, h)
+    vg = v.transpose(0, 2, 1, 3).reshape(b * kh, t, h)
 
     # Padding: pad keys land at positions >= t; causal masking vs real query
     # positions (< t for self-attention) excludes them. Pad queries are
     # sliced off after the call.
-    qg = jnp.pad(qg, ((0, 0), (0, sp - s), (0, 0), (0, 0)))
-    kg = jnp.pad(kg, ((0, 0), (0, tp - t), (0, 0), (0, 0)))
-    vg = jnp.pad(vg, ((0, 0), (0, tp - t), (0, 0), (0, 0)))
+    qg = jnp.pad(qg, ((0, 0), (0, 0), (0, sp - s), (0, 0)))
+    kg = jnp.pad(kg, ((0, 0), (0, tp - t), (0, 0)))
+    vg = jnp.pad(vg, ((0, 0), (0, tp - t), (0, 0)))
     if not causal and tp != t:
         raise NotImplementedError(
             "non-causal flash attention requires block-aligned key length "
@@ -75,5 +76,5 @@ def flash_attention(
         block_kv=bkv,
         interpret=interpret,
     )
-    out = out[:, :s].reshape(b, kh, s, g, h).transpose(0, 2, 1, 3, 4)
+    out = out[:, :, :s].reshape(b, kh, g, s, h).transpose(0, 3, 1, 2, 4)
     return out.reshape(b, s, n, h)

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.jaxcompat import tpu_compiler_params
-
 
 def _lru_kernel(a_ref, b_ref, o_ref, h_ref, *, chunk_t: int):
     t = pl.program_id(2)
@@ -34,15 +32,16 @@ def _lru_kernel(a_ref, b_ref, o_ref, h_ref, *, chunk_t: int):
     def _():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    a = a_ref[0]  # (chunk_t, block_w)
-    b = b_ref[0]
-
-    def step(i, h):
-        h = a[i] * h + b[i]
-        o_ref[0, i, :] = h
+    # rows are read through the refs: a dynamic index into a loaded
+    # (chunk_t, block_w) value would lower to dynamic_slice, which Mosaic
+    # does not implement
+    def step(i, h):  # h: (1, block_w)
+        row = pl.ds(i, 1)
+        h = a_ref[0, row, :] * h + b_ref[0, row, :]
+        o_ref[0, row, :] = h
         return h
 
-    h_ref[0] = jax.lax.fori_loop(0, chunk_t, step, h_ref[0])
+    h_ref[...] = jax.lax.fori_loop(0, chunk_t, step, h_ref[...])
 
 
 def linear_recurrence_p(
@@ -67,7 +66,7 @@ def linear_recurrence_p(
         out_shape=jax.ShapeDtypeStruct((bsz, s, w), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, block_w), a.dtype)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(a, b)

@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.jaxcompat import tpu_compiler_params
-
 from repro.core.engine import static_auto_distance
 from repro.core.refspec import PrefetchSpec
 
@@ -135,7 +133,7 @@ def streamed_matmul_p(
             pltpu.SemaphoreType.DMA((slots,)),
         ],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
     )(x, w)

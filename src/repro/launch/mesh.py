@@ -8,8 +8,7 @@ import, and smoke tests must keep seeing 1 device.
 from __future__ import annotations
 
 import jax
-
-from repro.jaxcompat import make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -17,11 +16,13 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     two-pod mesh (``pod x data x model``; ``pod`` is the DCN axis)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(model: int = 1) -> jax.sharding.Mesh:
     """Mesh over whatever devices exist (CPU tests / single host)."""
     n = len(jax.devices())
     assert n % model == 0, (n, model)
-    return make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh(
+        (n // model, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )

@@ -1264,6 +1264,9 @@ def main() -> int:
         ap.error("--verify-schedule requires --param-kind pinned_host "
                  "or disk_host (device-resident weights have no schedule)")
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = make_local_mesh(model=args.model_parallel)
     distance = args.distance if args.distance == AUTO else int(args.distance)

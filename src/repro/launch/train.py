@@ -59,8 +59,9 @@ def build_trainer(
     of each state group — the paper's one-line placement change.  With
     ``HOST_OPT`` the AdamW state lives at the pinned-host kind between
     steps; the runtime streams it to the device for the update and back
-    (on backends without host-offload execution the kinds fall back to
-    device with identical program topology, see memkind docs).
+    (on the CPU, which cannot execute host placements, the kinds fall back
+    to device with identical program topology; other backends raise, see
+    memkind docs).
 
     ``stream_opt`` upgrades a host-kind optimizer policy from bulk
     step-boundary copies to the transfer-engine streamed update: moments
@@ -546,6 +547,9 @@ def main() -> int:
                  "(pinned_host or disk_host)")
 
     logging.basicConfig(level=logging.INFO, format="%(levelname)s %(message)s")
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     # elastic: degrade the model axis instead of asserting when the device
     # count changed since the job was first launched

@@ -114,8 +114,7 @@ def elastic_local_mesh(model: int = 1):
     can host — the 2-device → 1-device resume keeps working instead of
     crashing on ``2 % 2 != 0``."""
     import jax
-
-    from repro.jaxcompat import make_mesh
+    from jax.sharding import AxisType
 
     n = len(jax.devices())
     m = max(1, min(model, n))
@@ -128,7 +127,7 @@ def elastic_local_mesh(model: int = 1):
             model, n, m,
         )
     shape, axes = elastic_mesh_shape(n, model=m, prefer_pods=False)
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 class RemeshRequired(RuntimeError):

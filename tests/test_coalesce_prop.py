@@ -121,7 +121,7 @@ def test_mixed_device_host_passthrough(n, dtype_idx):
     n=hst.integers(min_value=0, max_value=11),
     dtype_idx=hst.integers(min_value=0, max_value=len(_DTYPES) - 1),
 )
-def test_disk_tier_roundtrip_through_engine(n, dtype_idx, tmp_path_factory=None):
+def test_disk_tier_roundtrip_through_engine(n, dtype_idx):
     """Full engine path for spill-store (DiskHost) groups: disk -> host
     staging -> pack -> device must equal device_put of the original."""
     import tempfile
@@ -158,10 +158,9 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.engine import TransferEngine
 from repro.core.spillstore import SpillStore
-from repro.jaxcompat import make_mesh
-
 assert len(jax.devices()) == 2, jax.devices()
-mesh = make_mesh((1, 2), ("data", "model"))
+mesh = jax.make_mesh((1, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 SH = NamedSharding(mesh, P(None, "model"))
 SH0 = NamedSharding(mesh, P("model"))
 REP = NamedSharding(mesh, P())

@@ -12,9 +12,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 from proptest import given, settings, strategies as hst
 
-from repro import jaxcompat
 from repro.core import memkind as mk
 from repro.core.hoststream import HostStreamExecutor, StreamStats
 from repro.core.offload import offload
@@ -41,7 +41,7 @@ def test_kind_resolution_fallback_only_for_host():
 
 def test_sharding_for_every_kind_is_constructible():
     """Logical kinds must map onto *some* tier on every backend."""
-    mesh = jaxcompat.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     for kind in (mk.DEVICE, mk.PINNED_HOST, mk.UNPINNED_HOST):
         s = mk.sharding_for(mesh, jax.sharding.PartitionSpec(), kind)
         y = jax.device_put(jnp.arange(4.0), s)
@@ -49,7 +49,7 @@ def test_sharding_for_every_kind_is_constructible():
 
 
 def test_place_round_trip():
-    mesh = jaxcompat.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     x = jnp.arange(16.0)
     y = mk.place(x, mesh, jax.sharding.PartitionSpec(), mk.DEVICE)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
@@ -224,3 +224,43 @@ def test_hoststream_writeback_rw_access():
                      prefetch=PrefetchSpec(buffer_size=2, elements_per_fetch=1, distance=1))
     assert len(outs) == 4
     np.testing.assert_array_equal(outs[3], np.full((2, 2), 6.0, np.float32))
+
+
+def test_host_kind_never_falls_back_to_device_off_cpu(monkeypatch):
+    """Off the CPU a host kind the backend cannot execute raises instead of
+    landing in HBM; the CPU keeps its single-memory fallback."""
+    assert mk.resolve_kind("pinned_host") in (mk.PINNED_HOST, mk.DEVICE)
+    monkeypatch.setattr(mk, "_on_cpu", lambda: False)
+    monkeypatch.setattr(mk, "host_offload_supported", lambda: False)
+    with pytest.raises(RuntimeError, match="refusing"):
+        mk.resolve_kind("pinned_host")
+    with pytest.raises(RuntimeError, match="refusing"):
+        mk.resolve_kind(mk.DISK_HOST)
+    assert mk.resolve_kind("device") == mk.DEVICE
+    monkeypatch.setattr(mk, "backend_memory_kinds", lambda: ("device",))
+    with pytest.raises(RuntimeError, match="refusing"):
+        mk.backend_kind_string("pinned_host")
+    assert mk.backend_kind_string("device") == "device"
+
+
+def test_offload_probe_error_propagates_off_cpu(monkeypatch):
+    """The capability probe swallows a compile error only on the CPU."""
+    real_jit = jax.jit
+
+    def broken_jit(*a, **kw):
+        raise RuntimeError("probe compile failed")
+
+    monkeypatch.setattr(
+        mk, "backend_memory_kinds", lambda: ("device", "pinned_host")
+    )
+    monkeypatch.setattr(jax, "jit", broken_jit)
+    try:
+        mk.host_offload_supported.cache_clear()
+        assert mk.host_offload_supported() is False  # CPU: unsupported
+        mk.host_offload_supported.cache_clear()
+        monkeypatch.setattr(mk, "_on_cpu", lambda: False)
+        with pytest.raises(RuntimeError, match="probe compile failed"):
+            mk.host_offload_supported()
+    finally:
+        monkeypatch.setattr(jax, "jit", real_jit)
+        mk.host_offload_supported.cache_clear()

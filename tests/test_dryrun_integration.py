@@ -19,8 +19,8 @@ from repro.configs import get_smoke_config
 from repro.launch import dryrun as dr
 from repro.roofline import analysis as ra
 
-from repro.jaxcompat import make_mesh
-mesh = make_mesh((2, 4), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 out = {}
 for arch, shape in (("olmo-1b", "train_4k"), ("olmo-1b", "decode_32k"),
                     ("mixtral-8x7b", "train_4k")):

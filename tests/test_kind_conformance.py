@@ -298,11 +298,11 @@ from repro.core.engine import TransferEngine
 from repro.core.hoststream import HostStreamExecutor, StreamStats
 from repro.core.refspec import AUTO, PrefetchSpec
 from repro.core.spillstore import SpillStore
-from repro.jaxcompat import make_mesh
 
 N_GROUPS = 4
 assert len(jax.devices()) == 2, jax.devices()
-mesh = make_mesh((1, 2), ("data", "model"))
+mesh = jax.make_mesh((1, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 # one model-sharded leaf, one replicated bf16 leaf per group
 shardings = {"w": NamedSharding(mesh, P(None, "model")),
              "b": NamedSharding(mesh, P())}

@@ -562,6 +562,9 @@ _ENV = {
     # the re-mesh resumes run fully sanitized: kill + reshard + replay must
     # produce zero transfer-hazard reports, not just bitwise losses
     "REPRO_SANITIZE": "1",
+    # the CLI points JAX's persistent compile cache into the checkout;
+    # tests keep it off
+    "JAX_ENABLE_COMPILATION_CACHE": "false",
 }
 
 

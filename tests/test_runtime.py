@@ -8,9 +8,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 from proptest import given, settings, strategies as hst
 
-from repro import jaxcompat
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_smoke_config
 from repro.data.loader import PrefetchLoader
@@ -134,7 +134,7 @@ def test_checkpoint_elastic_reshard(tmp_path):
     mgr = CheckpointManager(tmp_path)
     t = _tree()
     mgr.save(1, t, blocking=True)
-    mesh = jaxcompat.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     sh = jax.tree.map(
         lambda _: jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec()), t
     )
@@ -353,10 +353,9 @@ from functools import partial
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from jax.experimental.shard_map import shard_map
-from repro.jaxcompat import make_mesh
 from repro.optim import grad_compress as gc
 
-mesh = make_mesh((2,), ("pod",))
+mesh = jax.make_mesh((2,), ("pod",), axis_types=(jax.sharding.AxisType.Auto,))
 # two pods with VERY different gradient magnitudes: the seed bug psummed
 # raw int8 quantized under per-pod scales, inflating pod 0's contribution
 # by pmax/scale0 ~ 1e4

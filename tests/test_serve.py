@@ -351,12 +351,12 @@ import jax
 from jax.sharding import PartitionSpec as P
 from repro.configs import get_smoke_config
 from repro.core import memkind as mk
-from repro.jaxcompat import make_mesh
 from repro.models import transformer
 from repro.parallel import sharding as sh
 
 cfg = get_smoke_config("smollm-360m")
-mesh = make_mesh((1, 2), ("data", "model"))
+mesh = jax.make_mesh((1, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 plan = sh.make_plan(mesh, mode="serve")
 batch = 2
 caches = jax.jit(lambda: transformer.init_caches(cfg, batch, 16))()

@@ -5,9 +5,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
-from repro import jaxcompat
 from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.models import transformer
 from repro.parallel import sharding as sh
@@ -126,7 +125,7 @@ def test_sharder_end_to_end_single_device():
     """Sharder-constrained train step runs on 1 CPU device (constraints are
     no-ops on a trivial mesh but the code path is exercised)."""
     cfg = get_smoke_config("olmo-1b")
-    mesh = jaxcompat.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     plan = sh.make_plan(mesh, "train")
     params, opt = st.init_train_state(jax.random.PRNGKey(0), cfg)
     sharder = sh.make_sharder(plan, params, 2, seq_len=16, seq_shard=True)
