@@ -1,0 +1,7 @@
+"""Share of the traced window the transfer engine spent packing weight groups
+and KV pages into their staging buffers (``repro.engine.pack``), in %."""
+from chipbench.metrics._spans import share
+
+
+def read(run):
+    return share(run, "serve", "repro.engine.pack")

@@ -87,6 +87,7 @@ import numpy as np
 from jax import lax
 
 from repro.core.schedcheck import HazardError, HazardSanitizer, sanitize_enabled
+from repro.core.spans import span
 
 log = logging.getLogger("repro.engine")
 
@@ -811,8 +812,6 @@ class TransferEngine:
         self._disk_cond = threading.Condition()
         self._disk_controller: Optional[AdaptiveDistance] = None
         self._disk_window = max(1, self.config.disk_slots)
-        #: total disk staging buffers ever allocated (reuse metric)
-        self.disk_staging_allocs: int = 0
         #: the (emulated) disk is its own serial resource
         self._disk_link_lock = threading.Lock()
         #: True when the last close() abandoned a live worker thread
@@ -983,7 +982,6 @@ class TransferEngine:
             free = self._disk_free[dsig]
             if free:
                 return free.pop()
-            self.disk_staging_allocs += 1
             return layout.new_staging()
         except BaseException:
             # allocation failed (e.g. MemoryError in a RAM-constrained run):
@@ -1156,7 +1154,8 @@ class TransferEngine:
         order (FIFO worker + ordered tickets ⇒ paper's per-device ordering)."""
         tickets = sorted(self._pending_wb, key=lambda t: t.index)
         self._pending_wb = []
-        out = [t.result() for t in tickets]
+        with span("stream.drain.wait"):
+            out = [t.result() for t in tickets]
         if self.sanitizer is not None:
             # only reached when every result landed: a failed drain keeps
             # its keys pending, so a restart must discard before re-fetching
@@ -1172,6 +1171,19 @@ class TransferEngine:
             self.sanitizer.on_drained([t.key for t in self._pending_wb])
         self._pending_wb = []
         return n
+
+    def settle(self) -> None:
+        """Block until every transfer submitted so far has completed or
+        failed: a barrier queued behind them on the FIFO worker.  A failed
+        fetch settles the engine before its error surfaces, so the caller
+        sees no transfer of the abandoned window still running (nor a disk
+        buffer still checked out of the read-ahead window).  A wedged worker
+        is waited on for at most ``close_timeout_s``."""
+        if self._worker is None or not self._worker.is_alive():
+            return
+        done = threading.Event()
+        self._tasks.put(("barrier", done))
+        done.wait(timeout=self.config.close_timeout_s)
 
     # -- worker thread -------------------------------------------------------
     def _retry_loop(self, op, counter, what: str):
@@ -1213,12 +1225,13 @@ class TransferEngine:
                             # stage-2-on-stage-1 wait: zero once the disk
                             # read-ahead window covers the disk latency
                             t0 = time.perf_counter()
-                            ticket._event.wait()
-                            if ticket._error is not None:
-                                raise ticket._error
-                            residual = ticket.ready_at - time.perf_counter()
-                            if residual > 0:
-                                _sleep_precise(residual)
+                            with span("engine.disk.wait"):
+                                ticket._event.wait()
+                                if ticket._error is not None:
+                                    raise ticket._error
+                                residual = ticket.ready_at - time.perf_counter()
+                                if residual > 0:
+                                    _sleep_precise(residual)
                             fut.disk_wait_s = time.perf_counter() - t0
                             self._observe_disk_wait(fut.disk_wait_s)
                             fut.retries += ticket.retries
@@ -1233,7 +1246,9 @@ class TransferEngine:
                                 # (src_leaves view into it), so a retried
                                 # pack re-reads intact staged bytes
                                 flat = self._retry_loop(
-                                    lambda: self._put_coalesced(sig, layout, src_leaves),
+                                    lambda: self._put_coalesced(
+                                        sig, layout, src_leaves, fut.index
+                                    ),
                                     fut, f"H2D (group {fut.index})",
                                 )
                             else:  # everything already device-resident
@@ -1246,35 +1261,42 @@ class TransferEngine:
                         ready_at = self._emulate(link, fut.n_requests, fut.nbytes)
                         fut._complete(flat=flat, ready_at=ready_at)
                     else:
-                        tree = self._retry_loop(
-                            lambda: self._put_per_leaf(group, shardings),
-                            fut, f"H2D per-leaf (group {fut.index})",
-                        )
+                        with span("engine.put", group=fut.index, bytes=fut.nbytes):
+                            tree = self._retry_loop(
+                                lambda: self._put_per_leaf(group, shardings),
+                                fut, f"H2D per-leaf (group {fut.index})",
+                            )
                         ready_at = self._emulate(link, fut.n_requests, fut.nbytes)
                         fut._complete(device_tree=tree, ready_at=ready_at)
                 elif kind == "d2h":
                     _, ticket, group_out = task
-                    host = self._retry_loop(
-                        lambda: jax.device_get(group_out),
-                        ticket, f"D2H (group {ticket.index})",
-                    )
+                    with span("engine.d2h", group=ticket.index, bytes=ticket.nbytes):
+                        host = self._retry_loop(
+                            lambda: jax.device_get(group_out),
+                            ticket, f"D2H (group {ticket.index})",
+                        )
                     ready_at = self._emulate(link, ticket.n_requests, ticket.nbytes)
                     ticket.ready_at = ready_at
                     ticket._host = host
                     ticket._event.set()
+                elif kind == "barrier":
+                    task[1].set()
             except BaseException as e:  # noqa: BLE001 — surface on the waiter
                 obj = task[1]
                 obj._error = e
                 obj._event.set()
 
-    def _put_coalesced(self, sig: tuple, layout, src_leaves):
+    def _put_coalesced(self, sig: tuple, layout, src_leaves, index: int):
         """One attempt of the coalesced H2D: stage, put, block.  On a fault
         the staging buffer is dropped, not recycled — a half-issued put may
         still alias it; the pool reallocates on the next attempt."""
         staging = self._acquire_staging(sig, layout)
-        layout.pack_into(src_leaves, staging)
-        flat = layout.put_staged(staging)
-        jax.block_until_ready(flat)
+        nbytes = layout.payload_bytes
+        with span("engine.pack", group=index, bytes=nbytes):
+            layout.pack_into(src_leaves, staging)
+        with span("engine.put", group=index, bytes=nbytes):
+            flat = layout.put_staged(staging)
+            jax.block_until_ready(flat)
         if not layout.any_alias(flat, staging):
             # the device holds its own copy: recycle now
             self._release_staging(sig, staging)
@@ -1319,7 +1341,8 @@ class TransferEngine:
                         disk_leaves = [verify_disk_leaf(x) for x in disk_leaves]
                     buf = self._acquire_disk_staging(ticket.sig, layout)
                     # the copy out of the memory-mapped view IS the disk read
-                    layout.pack_into(disk_leaves, buf)
+                    with span("disk.read", bytes=ticket.nbytes):
+                        layout.pack_into(disk_leaves, buf)
                     raw = buf.view(np.uint8)
                     views = [
                         raw[o : o + nb].view(dt).reshape(shape)

@@ -35,6 +35,7 @@ import jax
 
 from repro.core.engine import AdaptiveDistance, EngineConfig, TransferEngine
 from repro.core.refspec import Access, PrefetchSpec
+from repro.core.spans import span
 
 __all__ = ["StreamStats", "HostStreamExecutor"]
 
@@ -412,15 +413,18 @@ class HostStreamExecutor:
         #: writeback tickets issued this run (retry accounting at drain)
         wb_tickets: list = []
 
-        def waited(fut) -> float:
+        def waited(i: int, fut) -> float:
             """fut.wait() plus retry/give-up accounting: absorbed transient
             faults land in ``st.retries``; a surfaced (permanent) fault
-            counts one give-up and re-raises to the caller."""
+            counts one give-up, settles the engine and re-raises to the
+            caller."""
             try:
-                w = fut.wait()
+                with span("stream.wait", group=i):
+                    w = fut.wait()
             except BaseException:
                 st.retries += fut.retries
                 st.give_ups += 1
+                self._engine.settle()
                 raise
             st.retries += fut.retries
             return w
@@ -428,20 +432,23 @@ class HostStreamExecutor:
         if mode == "eager":
             # bulk transfer first — the paper's original kernel invocation
             futs = [submit(i) for i in range(n)]
-            for fut in futs:
-                w = waited(fut)
+            for i, fut in enumerate(futs):
+                w = waited(i, fut)
                 st.transfer_wait_s += w
                 st.wait_per_group.append(w)
                 st.disk_wait_s += fut.disk_wait_s
                 st.disk_wait_per_group.append(fut.disk_wait_s)
             t0 = time.perf_counter()
             for i, fut in enumerate(futs):
+                with span("stream.unpack", group=i):
+                    buf = fut.group()
                 carry = self._step(
-                    i, carry, fut.group(), outs, st, wb_tickets,
+                    i, carry, buf, outs, st, wb_tickets,
                     wb_key=group_keys[i] if group_keys is not None else None,
                 )
                 live_bytes -= fut.nbytes
-            jax.block_until_ready(carry)
+            with span("stream.sync.wait"):
+                jax.block_until_ready(carry)
             st.compute_s += time.perf_counter() - t0
         else:
             inflight: "OrderedDict[int, Any]" = OrderedDict()
@@ -454,7 +461,7 @@ class HostStreamExecutor:
                 fut = inflight.pop(i)
                 # the paper's blocking fetch: the core stalls until data
                 # lands (zero once the window covers the link latency)
-                w = waited(fut)
+                w = waited(i, fut)
                 st.transfer_wait_s += w
                 st.wait_per_group.append(w)
                 st.distance_trace.append(distance)
@@ -463,14 +470,17 @@ class HostStreamExecutor:
                 if controller is not None:
                     distance = controller.observe(w)
                 t0 = time.perf_counter()
+                with span("stream.unpack", group=i):
+                    buf = fut.group()
                 carry = self._step(
-                    i, carry, fut.group(), outs, st, wb_tickets,
+                    i, carry, buf, outs, st, wb_tickets,
                     wb_key=group_keys[i] if group_keys is not None else None,
                 )
                 live_bytes -= fut.nbytes
                 st.compute_s += time.perf_counter() - t0
             t0 = time.perf_counter()
-            jax.block_until_ready(carry)
+            with span("stream.sync.wait"):
+                jax.block_until_ready(carry)
             st.compute_s += time.perf_counter() - t0
 
         if self._writeback and self._engine.config.async_writeback:

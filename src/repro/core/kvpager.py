@@ -59,6 +59,7 @@ from repro.core import memkind as mk
 from repro.core.engine import AdaptiveDistance, TransferEngine
 from repro.core.hoststream import StreamStats
 from repro.core.refspec import AUTO
+from repro.core.spans import span
 
 __all__ = [
     "KVPagerConfig",
@@ -358,7 +359,14 @@ class PageStream:
             fut = self._submit(key, tree)
             self._inflight.pop(key)
         self._owner.pop(key, None)
-        w = fut.wait()
+        try:
+            with span("kv.wait"):
+                w = fut.wait()
+        except BaseException:
+            # the rest of the window lands before the error surfaces: the
+            # failed step leaves no page fetch running behind it
+            self._engine.settle()
+            raise
         stats.n_transfers += 1
         stats.n_groups += 1
         stats.h2d_requests += fut.n_requests
@@ -647,27 +655,28 @@ class KVPager:
         and re-home them at the cold kind."""
         if not self._pending_demotions:
             return
-        pending, self._pending_demotions = self._pending_demotions, []
-        t0 = time.perf_counter()
-        hosts = self.engine.drain_writebacks()
-        stats.writeback_drain_s += time.perf_counter() - t0
-        for (table, p), host in zip(pending, hosts):
-            nb = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(host))
-            stats.n_transfers += 1
-            stats.d2h_requests += len(jax.tree.leaves(host))
-            stats.bytes_d2h += nb
-            rec = table.records[p]
-            key = self._fetch_key(table, p)
-            if self.kind == mk.DISK_HOST:
-                self.store.put(key, host)
-                host = self.store.get(key)
-            if rec.shared is not None:
-                rec.shared.host = host
-                rec.shared.wb_pending = False
-            else:
-                rec.host = host
-            rec.state = _COLD
-            self.demoted_groups += 1
+        with span("kv.flush"):
+            pending, self._pending_demotions = self._pending_demotions, []
+            t0 = time.perf_counter()
+            hosts = self.engine.drain_writebacks()
+            stats.writeback_drain_s += time.perf_counter() - t0
+            for (table, p), host in zip(pending, hosts):
+                nb = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(host))
+                stats.n_transfers += 1
+                stats.d2h_requests += len(jax.tree.leaves(host))
+                stats.bytes_d2h += nb
+                rec = table.records[p]
+                key = self._fetch_key(table, p)
+                if self.kind == mk.DISK_HOST:
+                    self.store.put(key, host)
+                    host = self.store.get(key)
+                if rec.shared is not None:
+                    rec.shared.host = host
+                    rec.shared.wb_pending = False
+                else:
+                    rec.host = host
+                rec.state = _COLD
+                self.demoted_groups += 1
 
     def cold_keys(self) -> "OrderedDict[str, tuple]":
         """Every cold page of every active request, slot-major then page
@@ -695,41 +704,42 @@ class KVPager:
     def view(self, stats: StreamStats) -> tuple:
         """This step's per-slot page view: hot pages by reference, cold
         pages popped from the stream, future pages the shared zero page."""
-        view = []
-        for slot in range(self.slots):
-            table = self._by_slot.get(slot)
-            if table is None:
-                view.append((self._zero_page,) * self.n_pages)
-                continue
-            pages = []
-            for p, rec in enumerate(table.records):
-                if rec.state == _DEVICE:
-                    pages.append(rec.dev)
-                elif rec.state == _ZERO:
-                    pages.append(self._zero_page)
-                else:
-                    if rec.state == _WB or (
-                        rec.shared is not None and rec.shared.host is None
-                    ):
-                        # demoted but never flushed (or aliasing a shared
-                        # writeback still in the D2H queue) — should not
-                        # happen in the serve loop; flush so the host
-                        # bytes exist
-                        self.flush_demotions(stats)
-                    dev = self.stream.pop(
-                        table.rid, self._fetch_key(table, p),
-                        self._cold_home(rec), stats,
-                    )
-                    if self.kind == mk.DEVICE or p >= self._hot_floor(table):
-                        # home tier is the device (or the page re-entered
-                        # the hot window after a readmit): promote
-                        rec.dev = dev
-                        rec.state = _DEVICE
-                    pages.append(dev)
-            view.append(tuple(pages))
-        # one controller observation per request per step (see PageStream)
-        self.stream.step_done()
-        return tuple(view)
+        with span("kv.view"):
+            view = []
+            for slot in range(self.slots):
+                table = self._by_slot.get(slot)
+                if table is None:
+                    view.append((self._zero_page,) * self.n_pages)
+                    continue
+                pages = []
+                for p, rec in enumerate(table.records):
+                    if rec.state == _DEVICE:
+                        pages.append(rec.dev)
+                    elif rec.state == _ZERO:
+                        pages.append(self._zero_page)
+                    else:
+                        if rec.state == _WB or (
+                            rec.shared is not None and rec.shared.host is None
+                        ):
+                            # demoted but never flushed (or aliasing a shared
+                            # writeback still in the D2H queue) — should not
+                            # happen in the serve loop; flush so the host
+                            # bytes exist
+                            self.flush_demotions(stats)
+                        dev = self.stream.pop(
+                            table.rid, self._fetch_key(table, p),
+                            self._cold_home(rec), stats,
+                        )
+                        if self.kind == mk.DEVICE or p >= self._hot_floor(table):
+                            # home tier is the device (or the page re-entered
+                            # the hot window after a readmit): promote
+                            rec.dev = dev
+                            rec.state = _DEVICE
+                        pages.append(dev)
+                view.append(tuple(pages))
+            # one controller observation per request per step (see PageStream)
+            self.stream.step_done()
+            return tuple(view)
 
     def update_current(self, new_cache: Pytree) -> None:
         """Re-slice each active slot's current page out of the decode

@@ -46,6 +46,7 @@ from repro.core.kvpager import (
 )
 from repro.core.refspec import AUTO
 from repro.core.residency import ResidencyCache
+from repro.core.spans import span
 from repro.core.spillstore import SpillStore
 from repro.launch.mesh import make_local_mesh
 from repro.parallel import sharding as sh
@@ -543,23 +544,25 @@ class ServeSession:
                 break
             rid = self.queue.popleft()
             req = self.requests[rid]
-            s = len(req.prompt)
-            width = self._bucket_len(s)
-            padded = np.zeros((width,), np.int32)
-            padded[:s] = req.prompt
-            logits, cache = self._prefill(
-                self.params,
-                _prompt_batch(self.cfg, padded[None, :]),
-                jnp.asarray(s - 1, jnp.int32),
-            )
-            tok = np.asarray(self._argmax(logits))[0]  # scalar / (n_codebooks,)
-            req.next_token = tok
-            req.emitted.append(_emit(self.cfg, tok))
-            emitted[rid] = req.emitted[-1]
-            self._slot_of[rid] = slot
-            self.pager.admit(
-                rid, slot, cache, s, prefix_keys=self._prefix_keys(req)
-            )
+            with span("serve.admit", rid=rid):
+                s = len(req.prompt)
+                width = self._bucket_len(s)
+                padded = np.zeros((width,), np.int32)
+                padded[:s] = req.prompt
+                logits, cache = self._prefill(
+                    self.params,
+                    _prompt_batch(self.cfg, padded[None, :]),
+                    jnp.asarray(s - 1, jnp.int32),
+                )
+                with span("serve.sync.wait"):
+                    tok = np.asarray(self._argmax(logits))[0]  # scalar / (n_codebooks,)
+                req.next_token = tok
+                req.emitted.append(_emit(self.cfg, tok))
+                emitted[rid] = req.emitted[-1]
+                self._slot_of[rid] = slot
+                self.pager.admit(
+                    rid, slot, cache, s, prefix_keys=self._prefix_keys(req)
+                )
             if req.done:  # gen == 1: nothing left to decode
                 self._retire(rid)
         self.pager.flush_demotions(self.stats)
@@ -634,50 +637,52 @@ class ServeSession:
         """One decode step over every active slot.  Returns ``{rid: token}``
         for tokens emitted this step (including first tokens of requests
         admitted at the end of the step)."""
-        if not self._slot_of and (self.queue or self._readmit_queue):
-            return self.admit_pending()
-        wait0 = self.stats.transfer_wait_s
-        fetch0 = self.param_stats.unique_group_fetches
+        with span("serve.step", step=self.n_steps):
+            if not self._slot_of and (self.queue or self._readmit_queue):
+                return self.admit_pending()
+            wait0 = self.stats.transfer_wait_s
+            fetch0 = self.param_stats.unique_group_fetches
 
-        tokens = np.zeros(self._tok_shape(), np.int32)
-        pos = np.zeros((self.slots,), np.int32)
-        by_slot = {}
-        for rid, slot in self._slot_of.items():
-            req = self.requests[rid]
-            tokens[slot] = req.next_token
-            pos[slot] = self.pager.tables[rid].pos
-            by_slot[slot] = req
+            tokens = np.zeros(self._tok_shape(), np.int32)
+            pos = np.zeros((self.slots,), np.int32)
+            by_slot = {}
+            for rid, slot in self._slot_of.items():
+                req = self.requests[rid]
+                tokens[slot] = req.next_token
+                pos[slot] = self.pager.tables[rid].pos
+                by_slot[slot] = req
 
-        # pop this step's cold pages (waits only where the window fell
-        # short), then speculatively prefetch the same cold set for the
-        # next step — those transfers overlap the decode compute below
-        view = self.pager.view(self.stats)
-        self.pager.prefetch()
-        logits, new_cache = self._step(
-            self.params, view, _step_batch(self.cfg, tokens), jnp.asarray(pos)
-        )
-        nxt = np.asarray(self._argmax(logits))  # blocks on the decode compute
-        self.pager.update_current(new_cache)
+            # pop this step's cold pages (waits only where the window fell
+            # short), then speculatively prefetch the same cold set for the
+            # next step — those transfers overlap the decode compute below
+            view = self.pager.view(self.stats)
+            self.pager.prefetch()
+            logits, new_cache = self._step(
+                self.params, view, _step_batch(self.cfg, tokens), jnp.asarray(pos)
+            )
+            with span("serve.sync.wait"):
+                nxt = np.asarray(self._argmax(logits))  # blocks on the decode compute
+            self.pager.update_current(new_cache)
 
-        emitted = {}
-        for slot, req in by_slot.items():
-            req.next_token = nxt[slot]
-            req.emitted.append(_emit(self.cfg, nxt[slot]))
-            emitted[req.rid] = req.emitted[-1]
-            table = self.pager.tables[req.rid]
-            table.pos += 1
-            self.pager.advance(table)
-        self.pager.flush_demotions(self.stats)
-        for req in list(by_slot.values()):
-            if req.done:
-                self._retire(req.rid)
-        self.n_steps += 1
-        self.step_waits.append(self.stats.transfer_wait_s - wait0)
-        self.param_step_fetches.append(
-            self.param_stats.unique_group_fetches - fetch0
-        )
-        emitted.update(self.admit_pending())
-        return emitted
+            emitted = {}
+            for slot, req in by_slot.items():
+                req.next_token = nxt[slot]
+                req.emitted.append(_emit(self.cfg, nxt[slot]))
+                emitted[req.rid] = req.emitted[-1]
+                table = self.pager.tables[req.rid]
+                table.pos += 1
+                self.pager.advance(table)
+            self.pager.flush_demotions(self.stats)
+            for req in list(by_slot.values()):
+                if req.done:
+                    self._retire(req.rid)
+            self.n_steps += 1
+            self.step_waits.append(self.stats.transfer_wait_s - wait0)
+            self.param_step_fetches.append(
+                self.param_stats.unique_group_fetches - fetch0
+            )
+            emitted.update(self.admit_pending())
+            return emitted
 
     def run(self) -> dict:
         """Drive steps until every submitted request has finished.  Returns

@@ -27,6 +27,7 @@ from repro.core.hoststream import HostStreamExecutor, StreamStats
 from repro.core.refspec import PrefetchSpec
 from repro.core.residency import ResidencyCache
 from repro.core.schedcheck import analyze_train_schedule, verify_schedule
+from repro.core.spans import span
 from repro.core.weightstream import WeightStreamPlan, merge_expert_slice
 from repro.models import moe, transformer
 from repro.optim.adamw import (
@@ -921,11 +922,12 @@ def make_weight_streamed_train_step(
             fwd_groups = plan.fetch_thunks_forward(home, cache)
         else:
             fwd_groups = plan.fetch_groups_forward(home)
-        ex_f.run(
-            jnp.zeros(()), fwd_groups, mode=mode, prefetch=pf, stats=stats,
-            group_shardings=sh_fwd,
-            group_keys=[g.key for g in plan.groups],
-        )
+        with span("train.fwd"):
+            ex_f.run(
+                jnp.zeros(()), fwd_groups, mode=mode, prefetch=pf, stats=stats,
+                group_shardings=sh_fwd,
+                group_keys=[g.key for g in plan.groups],
+            )
 
         # phase B: reverse fetch order [middle reversed, embed]; grads drain
         # D2H.  The pinned turnaround set makes the first fetches cache hits.
@@ -935,11 +937,12 @@ def make_weight_streamed_train_step(
             ]
         else:
             bwd_groups = [fwd_groups[g.index] for g in bwd_order]
-        _, grad_outs = ex_b.run(
-            box["ct"], bwd_groups, mode=mode, prefetch=pf, stats=stats,
-            group_shardings=sh_bwd,
-            group_keys=[g.key for g in bwd_order],
-        )
+        with span("train.bwd"):
+            _, grad_outs = ex_b.run(
+                box["ct"], bwd_groups, mode=mode, prefetch=pf, stats=stats,
+                group_shardings=sh_bwd,
+                group_keys=[g.key for g in bwd_order],
+            )
 
         step_no = int(np.asarray(opt["step"])) + 1
         box["glob"] = globals_fn(tuple(box["sq"]), step_no)
@@ -964,18 +967,20 @@ def make_weight_streamed_train_step(
         o_groups = [
             {"g": grads_by_key[g.key], "s": opt["groups"][g.key]} for g in o_order
         ]
-        _, o_outs = ex_o.run(
-            jnp.zeros(()), o_groups, mode=mode, prefetch=pf, stats=opt_stats,
-            group_shardings=sh_o,
-            group_keys=[g.key for g in o_order],
-        )
+        with span("train.opt"):
+            _, o_outs = ex_o.run(
+                jnp.zeros(()), o_groups, mode=mode, prefetch=pf, stats=opt_stats,
+                group_shardings=sh_o,
+                group_keys=[g.key for g in o_order],
+            )
 
         new_home: dict = {}
         new_opt: dict = {}
-        for g, out in zip(o_order, o_outs):
-            p_new, s_new = _rehome(g, out["p"], out["s"], g.index)
-            new_home[g.key] = p_new
-            new_opt[g.key] = s_new
+        with span("train.rehome"):
+            for g, out in zip(o_order, o_outs):
+                p_new, s_new = _rehome(g, out["p"], out["s"], g.index)
+                new_home[g.key] = p_new
+                new_opt[g.key] = s_new
 
         glob = box["glob"]
         metrics = {
@@ -993,18 +998,19 @@ def make_weight_streamed_train_step(
         return new_state, metrics
 
     def step_fn(state, batch):
-        if cache is None:
-            return _step_body(state, batch)
-        try:
-            return _step_body(state, batch)
-        except BaseException:
-            # a step that died mid-optimizer leaves some cached groups
-            # refreshed and some stale — indistinguishable from outside, so
-            # the only safe cache is an empty one
-            cache.clear()
-            raise
-        finally:
-            cache.unpin_all()
+        with span("train.step", step=int(np.asarray(state["opt"]["step"])) + 1):
+            if cache is None:
+                return _step_body(state, batch)
+            try:
+                return _step_body(state, batch)
+            except BaseException:
+                # a step that died mid-optimizer leaves some cached groups
+                # refreshed and some stale — indistinguishable from outside,
+                # so the only safe cache is an empty one
+                cache.clear()
+                raise
+            finally:
+                cache.unpin_all()
 
     def close():
         for ex in (ex_f, ex_b, ex_o):
@@ -1306,11 +1312,13 @@ def make_weight_streamed_decode_step(
         parts = []
         for g, fut in futs:
             try:
-                w = fut.wait()
+                with span("stream.wait", group=g.index):
+                    w = fut.wait()
             except BaseException:
                 if st is not None:
                     st.retries += fut.retries
                     st.give_ups += 1
+                engine.settle()
                 raise
             if st is not None:
                 st.retries += fut.retries
@@ -1318,7 +1326,8 @@ def make_weight_streamed_decode_step(
                 st.wait_per_group.append(w)
                 st.disk_wait_s += fut.disk_wait_s
                 st.disk_wait_per_group.append(fut.disk_wait_s)
-            landed = fut.group()
+            with span("stream.unpack", group=g.index):
+                landed = fut.group()
             if residency is not None:
                 residency.put(
                     g.key, landed, plan.group_bytes(g, fetch=False)

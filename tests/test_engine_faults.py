@@ -397,3 +397,63 @@ def test_disk_stage_fault_mid_decode_recovers(tmp_path, monkeypatch):
     clean = run_with_timeout(lambda: run(False))
     faulted = run_with_timeout(lambda: run(True))
     np.testing.assert_array_equal(faulted, clean)
+
+
+# ---------------------------------------------------------------------------
+# a failed fetch settles the engine before its error surfaces
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("consumer", ["page_stream", "executor"])
+def test_failed_fetch_settles_the_engine_before_raising(tmp_path, monkeypatch, consumer):
+    """The disk stage of the first group fails while later groups of the
+    window are in flight behind it, on a transfer worker slowed to release
+    each disk buffer late.  When the error reaches the caller the rest of
+    the window has landed: no disk buffer is still checked out of the
+    read-ahead window, and the later groups arrive intact."""
+    import time
+
+    from repro.core.kvpager import PageStream
+
+    host, disk = _disk_groups(tmp_path, n=4)
+    real_acquire = TransferEngine._acquire_disk_staging
+    real_release = TransferEngine._release_disk_staging
+    armed = {"on": True}
+
+    def flaky_acquire(self, dsig, layout):
+        if armed["on"]:
+            armed["on"] = False
+            raise RuntimeError("injected disk-stage fault")
+        return real_acquire(self, dsig, layout)
+
+    def slow_release(self, dsig, buf):
+        time.sleep(0.2)
+        real_release(self, dsig, buf)
+
+    monkeypatch.setattr(TransferEngine, "_acquire_disk_staging", flaky_acquire)
+    monkeypatch.setattr(TransferEngine, "_release_disk_staging", slow_release)
+
+    def body():
+        with TransferEngine(EngineConfig(disk_slots=4, disk_max_slots=4)) as eng:
+            if consumer == "page_stream":
+                stream = PageStream(eng, distance=4)
+                for i, g in enumerate(disk):
+                    stream.push(0, f"p{i}", g)
+                with pytest.raises(RuntimeError, match="injected disk-stage"):
+                    stream.pop(0, "p0", disk[0], StreamStats())
+                assert eng._disk_in_use == 0
+                for i in (1, 2, 3):
+                    got = stream.pop(0, f"p{i}", disk[i], StreamStats())
+                    np.testing.assert_array_equal(np.asarray(got["x"]), host[i])
+            else:
+                pf = PrefetchSpec(buffer_size=4, distance=3)
+                with HostStreamExecutor(lambda c, g: c + g["x"].sum(), engine=eng) as ex:
+                    with pytest.raises(RuntimeError, match="injected disk-stage"):
+                        ex.run(jnp.zeros(()), disk, prefetch=pf)
+                    assert eng._disk_in_use == 0
+                    carry, _ = ex.run(jnp.zeros(()), disk, prefetch=pf)
+                    np.testing.assert_allclose(
+                        float(carry), sum(float(h.sum()) for h in host), rtol=1e-5
+                    )
+
+    run_with_timeout(body)
