@@ -112,8 +112,36 @@ _ALIGN = 64
 _WORD = 4
 
 
+#: a strided leaf is packed in tiles of this many elements along the
+#: staging's last axis by this many along the leaf's densest axis
+_TILE = (64, 1024)
+
+
 def _align(n: int) -> int:
     return (n + _ALIGN - 1) // _ALIGN * _ALIGN
+
+
+def _pack_leaf(dst: np.ndarray, src: np.ndarray) -> None:
+    """``np.copyto(dst, src)`` into a C-ordered staging view.
+
+    A home the runtime handed back in another axis order (a column-major
+    table from ``jax.device_get``) is gathered tile by tile: numpy's copy
+    walks the whole leaf in the staging's order, so each element read
+    lands on another page, and runs at a fraction of the tiled rate.
+    same_kind casting permits the canonicalizing f64->f32 / i64->i32
+    cast."""
+    last = dst.ndim - 1
+    fast = last if src.flags.c_contiguous or last < 1 else int(np.argmin(src.strides))
+    if fast == last:
+        np.copyto(dst, src, casting="same_kind")
+        return
+    kd, ks = _TILE
+    tile = [slice(None)] * dst.ndim
+    for k0 in range(0, dst.shape[last], kd):
+        tile[last] = slice(k0, k0 + kd)
+        for j0 in range(0, dst.shape[fast], ks):
+            tile[fast] = slice(j0, j0 + ks)
+            np.copyto(dst[tuple(tile)], src[tuple(tile)], casting="same_kind")
 
 
 def _sleep_precise(duration_s: float) -> None:
@@ -322,12 +350,18 @@ def group_signature(group: Pytree) -> tuple:
 
 
 def _aliases_host(flat: jax.Array, staging: np.ndarray) -> bool:
-    """True if the device array zero-copied the staging memory (some CPU
-    backends do) — in that case the buffer must NOT be recycled while the
-    array is alive."""
+    """True if the device array may share the staging memory — in that case
+    the buffer must NOT be recycled while the array is alive.
+
+    Only a CPU device can zero-copy a numpy buffer (some CPU backends do),
+    so a put that landed anywhere else holds its own copy.  On a CPU device
+    the pointers are compared, and one that will not report its pointer is
+    assumed to alias."""
+    if all(d.platform != "cpu" for d in flat.devices()):
+        return False
     try:
         return flat.unsafe_buffer_pointer() == staging.ctypes.data
-    except Exception:  # noqa: BLE001 — unknown backend: assume aliasing
+    except Exception:  # noqa: BLE001 — unknown CPU backend: assume aliasing
         return True
 
 
@@ -401,8 +435,7 @@ class GroupLayout:
         buf = staging.view(np.uint8)
         for i, off, shape, dtype, nbytes in self.metas:
             dst = buf[off : off + nbytes].view(dtype).reshape(shape)
-            # same_kind: permits the canonicalizing f64->f32 / i64->i32 cast
-            np.copyto(dst, leaves[i], casting="same_kind")
+            _pack_leaf(dst, np.asarray(leaves[i]))
         return staging
 
     def unpack(self, flat: jax.Array, src_leaves: list) -> Pytree:
@@ -578,7 +611,7 @@ class ShardedGroupLayout:
             buf = staging.view(np.uint8)
             for i, idx, off, shape, dtype, nbytes in es:
                 dst = buf[off : off + nbytes].view(dtype).reshape(shape)
-                np.copyto(dst, np.asarray(leaves[i])[idx], casting="same_kind")
+                _pack_leaf(dst, np.asarray(leaves[i])[idx])
         return stagings
 
     def put_staged(self, stagings: list[np.ndarray]) -> list:
@@ -794,8 +827,11 @@ class TransferEngine:
         self._layouts: dict[tuple, GroupLayout] = {}
         #: per-layout free list of reusable staging buffers (worker-owned)
         self._staging_free: dict[tuple, list[np.ndarray]] = {}
-        #: total staging buffers ever allocated (reuse-efficiency metric)
+        #: staging buffers allocated, and packs that reused a pooled one:
+        #: ``staging_reuses / (staging_reuses + staging_allocs)`` is the
+        #: pool's hit share over the coalesced packs
         self.staging_allocs: int = 0
+        self.staging_reuses: int = 0
         self._pending_wb: list[_WritebackTicket] = []
         #: the emulated link is one serial resource: every transfer's
         #: occupancy — worker H2D/D2H *and* the executor's blocking D2H
@@ -940,6 +976,7 @@ class TransferEngine:
         """
         free = self._staging_free[sig]
         if free:
+            self.staging_reuses += 1
             staging = free.pop()
             if self.sanitizer is not None:
                 self.sanitizer.on_staging_acquire(id(staging), from_pool=True)
@@ -1290,9 +1327,11 @@ class TransferEngine:
         """One attempt of the coalesced H2D: stage, put, block.  On a fault
         the staging buffer is dropped, not recycled — a half-issued put may
         still alias it; the pool reallocates on the next attempt."""
+        # a fresh buffer's first write pays a page fault per page
+        fresh = not self._staging_free[sig]
         staging = self._acquire_staging(sig, layout)
         nbytes = layout.payload_bytes
-        with span("engine.pack", group=index, bytes=nbytes):
+        with span("engine.pack", group=index, bytes=nbytes, fresh=fresh):
             layout.pack_into(src_leaves, staging)
         with span("engine.put", group=index, bytes=nbytes):
             flat = layout.put_staged(staging)

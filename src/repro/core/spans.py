@@ -9,7 +9,7 @@ a span costs about a microsecond.
 Names are ``repro.<layer>.<what>``.  A name ends in ``.wait`` exactly when
 its thread is blocked on another thread or on the device.  Spans are per
 step, per group or per page (never per leaf), and their metadata is small
-integers only.
+integers and flags only.
 """
 from __future__ import annotations
 

@@ -10,6 +10,7 @@ The invariants the paper's runtime depends on:
   * the adaptive controller converges instead of oscillating.
 """
 import json
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,7 @@ from repro.core.engine import (
     EngineConfig,
     GroupLayout,
     LinkModel,
+    ShardedGroupLayout,
     TransferEngine,
     static_auto_distance,
 )
@@ -72,6 +74,46 @@ def test_coalesced_equals_per_leaf_transfer_bitwise():
     ):
         assert a.dtype == b.dtype
         np.testing.assert_array_equal(a, b)
+
+
+def _strided_group(rng):
+    """Host leaves laid out the way ``jax.device_get`` can hand them back
+    (column-major, axes permuted), plus slices, reversals and broadcasts."""
+    base = rng.standard_normal((6, 5, 70, 3)).astype(np.float32)
+    return {
+        "col_major": np.asfortranarray(rng.standard_normal((1500, 90)).astype(np.float32)),
+        "bf16_col_major": np.asfortranarray(
+            rng.standard_normal((70, 2100)).astype(jnp.bfloat16)),
+        "permuted": base.transpose(2, 0, 3, 1).copy().transpose(1, 3, 0, 2),
+        "u8_permuted": np.ascontiguousarray(
+            rng.integers(0, 255, (3, 5, 1030)).astype(np.uint8).transpose(2, 1, 0)
+        ).transpose(2, 1, 0),
+        "gappy": base[:, ::2],
+        "reversed": base[::-1, :, ::-1],
+        "broadcast": np.broadcast_to(np.arange(70, dtype=np.int32)[:, None], (70, 1100)),
+        "f64_col_major": np.asfortranarray(np.arange(2400.0).reshape(1200, 2)),
+    }
+
+
+@pytest.mark.parametrize("sharded", [False, True], ids=["default", "sharded"])
+def test_strided_leaves_pack_bitwise(sharded):
+    """Leaves in any memory order pack, tile by tile where strided, to the
+    same bytes as the per-leaf transfer, for every dtype and placement."""
+    group = _strided_group(np.random.default_rng(7))
+    shardings = (
+        jax.sharding.SingleDeviceSharding(jax.devices()[0]) if sharded else None
+    )
+    results = {}
+    for name, cfg in (("coalesced", EngineConfig()), ("per_leaf", SEED_CONFIG)):
+        with TransferEngine(cfg) as eng:
+            fut = eng.submit_group(0, group, device_shardings=shardings)
+            fut.wait()
+            results[name] = jax.tree.map(np.asarray, fut.group())
+    for k, leaf in group.items():
+        a, b = results["coalesced"][k], results["per_leaf"][k]
+        assert a.dtype == b.dtype and a.shape == leaf.shape
+        np.testing.assert_array_equal(a, b)
+        np.testing.assert_array_equal(a, leaf.astype(a.dtype))
 
 
 def test_request_accounting_and_passthrough():
@@ -169,6 +211,90 @@ def test_staging_pool_is_reused_not_grown():
         for i, grp in enumerate(groups):
             eng.submit_group(i, grp).wait()
         assert eng.staging_allocs <= eng.config.staging_slots + 1
+
+
+class _LandedFlat:
+    """A put's flat buffer as its backend reports it: the device it landed
+    on, and its buffer pointer (``None``: the backend will not report one)."""
+
+    def __init__(self, platform: str, pointer) -> None:
+        self._device = SimpleNamespace(platform=platform)
+        self._pointer = pointer
+
+    def devices(self):
+        return [self._device]
+
+    def unsafe_buffer_pointer(self) -> int:
+        if self._pointer is None:
+            raise RuntimeError("no host pointer for this buffer")
+        return self._pointer
+
+    def block_until_ready(self):
+        return self
+
+
+def _landed(platform: str, reports: str, staging: np.ndarray) -> _LandedFlat:
+    pointer = {"raises": None, "staging": staging.ctypes.data,
+               "elsewhere": staging.ctypes.data + 64}[reports]
+    return _LandedFlat(platform, pointer)
+
+
+# (platform the put landed on, what its pointer reports, pool recycles?)
+_PLACEMENTS = [
+    ("tpu", "raises", True),      # an accelerator holds its own copy
+    ("cpu", "elsewhere", True),   # a CPU put that copied
+    ("cpu", "staging", False),    # a CPU put that zero-copied the buffer
+    ("cpu", "raises", False),     # a CPU backend that will not say: assume aliasing
+]
+
+
+@pytest.mark.parametrize("platform,reports,recycles", _PLACEMENTS)
+def test_staging_pool_recycles_by_placement(monkeypatch, platform, reports, recycles):
+    """The pool recycles a staging buffer unless the put could share its
+    memory, which only a CPU device can; every pack either reuses a pooled
+    buffer or allocates one."""
+    monkeypatch.setattr(
+        GroupLayout, "put_staged",
+        lambda self, staging: _landed(platform, reports, staging),
+    )
+    rng = np.random.default_rng(5)
+    with TransferEngine() as eng:
+        for i in range(32):
+            eng.submit_group(i, {"x": rng.standard_normal((16,)).astype(np.float32)}).wait()
+        assert eng.staging_allocs + eng.staging_reuses == 32
+        assert eng.staging_allocs == (1 if recycles else 32)
+
+
+@pytest.mark.parametrize("platform,reports,recycles", _PLACEMENTS)
+def test_sharded_staging_pool_recycles_by_placement(monkeypatch, platform, reports, recycles):
+    """The same rule per device for sharded layouts: the per-device buffer
+    set is recycled unless some device's put could share its buffer."""
+    monkeypatch.setattr(
+        ShardedGroupLayout, "put_staged",
+        lambda self, stagings: [_landed(platform, reports, b) for b in stagings],
+    )
+    sharding = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    rng = np.random.default_rng(6)
+    with TransferEngine() as eng:
+        for i in range(32):
+            grp = {"x": rng.standard_normal((4, 4)).astype(np.float32)}
+            eng.submit_group(i, grp, device_shardings=sharding).wait()
+        assert eng.staging_allocs + eng.staging_reuses == 32
+        assert eng.staging_allocs == (1 if recycles else 32)
+
+
+def test_sharded_any_alias_asks_each_device():
+    """One device whose put may share its buffer keeps the whole set out of
+    the pool; a set whose puts all landed off the CPU goes back."""
+    grp = {"x": np.zeros((4,), np.float32)}
+    layout = ShardedGroupLayout(
+        grp, [jax.sharding.SingleDeviceSharding(jax.devices()[0])]
+    )
+    bufs = [np.zeros(4, np.uint32), np.zeros(4, np.uint32)]
+    tpu = [_landed("tpu", "raises", b) for b in bufs]
+    assert not layout.any_alias(tpu, bufs)
+    mixed = [tpu[0], _landed("cpu", "staging", bufs[1])]
+    assert layout.any_alias(mixed, bufs)
 
 
 # ---------------------------------------------------------------------------

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.engine import EngineConfig, LinkModel, TransferEngine
+from repro.core.engine import EngineConfig, GroupLayout, LinkModel, TransferEngine
 from repro.core.hoststream import HostStreamExecutor, StreamStats
 from repro.core.refspec import AUTO, PrefetchSpec
 from repro.core.spillstore import SpillStore
@@ -455,5 +455,48 @@ def test_failed_fetch_settles_the_engine_before_raising(tmp_path, monkeypatch, c
                     np.testing.assert_allclose(
                         float(carry), sum(float(h.sum()) for h in host), rtol=1e-5
                     )
+
+    run_with_timeout(body)
+
+
+# ---------------------------------------------------------------------------
+# staging pool under the sanitizer
+# ---------------------------------------------------------------------------
+
+
+def test_sanitized_pool_recycles_and_a_faulted_put_drops_its_buffer(monkeypatch):
+    """32 groups of one layout under the hazard sanitizer reuse their
+    staging buffer with no hazard; the buffer of a put that fails is
+    dropped (a half-issued put may still read it), not recycled."""
+    real_put = GroupLayout.put_staged
+    seen = {"n": 0, "faulted": None}
+
+    def put(self, staging):
+        seen["n"] += 1
+        if seen["n"] == 10:
+            seen["faulted"] = staging
+            raise RuntimeError("injected fault mid-put")
+        # the device gets memory of its own, as a put to an accelerator does
+        return real_put(self, staging.copy())
+
+    groups = [{"x": g} for g in _groups(32)]
+
+    def body():
+        with TransferEngine(EngineConfig(sanitize=True)) as eng:
+            monkeypatch.setattr(GroupLayout, "put_staged", put)
+            for i, grp in enumerate(groups):
+                fut = eng.submit_group(i, grp)
+                if i == 9:
+                    with pytest.raises(RuntimeError, match="injected fault"):
+                        fut.wait()
+                    continue
+                fut.wait()
+                np.testing.assert_array_equal(np.asarray(fut.group()["x"]), grp["x"])
+            assert eng.sanitizer.hazards == 0
+            assert eng.staging_allocs + eng.staging_reuses == 32
+            # the first buffer, and one to replace the dropped buffer
+            assert eng.staging_allocs == 2
+            (free,) = eng._staging_free.values()
+            assert len(free) == 1 and free[0] is not seen["faulted"]
 
     run_with_timeout(body)
