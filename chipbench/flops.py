@@ -1,47 +1,29 @@
 """Model FLOPs of the work a cell does, and the chip's published peaks.
 
-Counts are of the mathematics the model requires, from its sizes alone:
-two FLOPs per multiply-add, recomputation not counted.  ``N`` is the
-parameters every token multiplies by: the layers (norm scales included),
-the final norm and the output head (the tied table, used once as the head;
-the embedding lookup is no multiply).  Attention scores follow PaLM
-(arXiv:2204.02311, App. B): a causal token at context ``c`` costs
-``4 * layers * heads * head_dim * c`` in the forward pass (QK^T and AV), so
-a training token of a length-``s`` row costs ``6 * N + 6 * L * H * Q * s``.
+Each architecture (``chipbench/archs/<arch>.py``) counts its own FLOPs of the
+mathematics the model requires, from its sizes alone (``train_per_token``,
+``decode_token``, ``prefill``): two FLOPs per multiply-add, recomputation not
+counted, so a sparse layer counts the experts a token is routed to and a
+windowed layer the positions it sees.  The table of peaks is common to all.
+
+``matmul_params``, ``train_per_token``, ``decode_token`` and ``prefill`` are
+the dense architecture's, kept under the names they had here.
 """
 from __future__ import annotations
 
 import json
 from pathlib import Path
 
-from chipbench.model import Dims
-
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
+_DENSE = ("matmul_params", "train_per_token", "decode_token", "prefill")
 
 
-def matmul_params(dims: Dims) -> int:
-    return dims.non_embedding_params + dims.d * dims.vocab
+def __getattr__(name: str):
+    if name in _DENSE:
+        from chipbench.archs import dense
 
-
-def _attn(dims: Dims) -> int:
-    return dims.layers * dims.n_heads * dims.head_dim
-
-
-def train_per_token(dims: Dims, seq_len: int) -> float:
-    """Forward and backward FLOPs per token of a causal row of ``seq_len``."""
-    return 6.0 * matmul_params(dims) + 6.0 * _attn(dims) * seq_len
-
-
-def decode_token(dims: Dims, context: int) -> float:
-    """Forward FLOPs of one token that attends to ``context`` positions
-    (itself included)."""
-    return 2.0 * matmul_params(dims) + 4.0 * _attn(dims) * context
-
-
-def prefill(dims: Dims, prompt_len: int) -> float:
-    """Forward FLOPs of a causal prompt of ``prompt_len`` tokens."""
-    p = prompt_len
-    return 2.0 * matmul_params(dims) * p + 2.0 * _attn(dims) * p * (p + 1)
+        return getattr(dense, name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 def peaks(device_kind: str, path: Path = PEAKS) -> dict:

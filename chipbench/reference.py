@@ -1,16 +1,21 @@
-"""Plain reference of the dense decoders the benchmark runs.
+"""What the plain references of every architecture share.
 
-Straight ``jax.numpy`` in float32, every matrix product at
-``Precision.HIGHEST`` (a TPU otherwise multiplies float32 in bfloat16), with
-no kernel, cache or batching of the program's.  It follows the published
-Llama / OLMo block: pre-norm (RMSNorm with a scale, or OLMo's LayerNorm
-without scale or bias), rotary positions in the rotate-half layout, grouped
-query attention, SwiGLU, a final norm and the tied embedding as the output
-head.  It imports nothing of the program.
+Each architecture (``chipbench/archs/<arch>.py``) brings its own
+``Reference(dims, quant=False)`` with ``hidden``, ``logits`` and ``loss``, in
+float32 with every matrix product at ``Precision.HIGHEST`` (a TPU otherwise
+multiplies float32 in bfloat16), importing nothing of the program.  This
+module drives them: AdamW as the traffic file states it
+(:func:`train_readings`), and the gap of each served token below the
+reference's best logit (:func:`served_gaps`).  A reference whose weights do
+not fit the device at once sets ``blocked = True``: its ``hidden`` and
+``logits`` then run eagerly, each jitting its own blocks (a layer at a time),
+instead of under one jit over the whole tree.
 
 ``quant=True`` is the control: every operand of every matrix product is
-rounded to float8 (e4m3, one scale per tensor) on the way in, the step below
-the bfloat16 the configurations are served and trained in.
+rounded to float8 (:func:`fp8`: e4m3, one scale per tensor) on the way in,
+the step below the bfloat16 the configurations are served and trained in.
+
+``Reference`` is the dense architecture's, kept under the name it had here.
 """
 from __future__ import annotations
 
@@ -19,10 +24,16 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from chipbench.model import Dims
-
 HIGHEST = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0  # largest finite float8_e4m3fn
+
+
+def __getattr__(name: str):
+    if name == "Reference":
+        from chipbench.archs.dense import Reference
+
+        return Reference
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 def fp8(x: jax.Array) -> jax.Array:
@@ -31,89 +42,6 @@ def fp8(x: jax.Array) -> jax.Array:
     scale = F8_MAX / jnp.maximum(jnp.max(jnp.abs(x)), 1e-30)
     q = (x * scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) / scale
     return x + jax.lax.stop_gradient(q - x)
-
-
-class Reference:
-    def __init__(self, dims: Dims, *, quant: bool = False) -> None:
-        self.dims = dims
-        self.quant = quant
-
-    def mm(self, spec: str, a, b):
-        if self.quant:
-            a, b = fp8(a), fp8(b)
-        return jnp.einsum(spec, a, b, precision=HIGHEST)
-
-    def norm(self, x, scale):
-        eps = self.dims.eps
-        if self.dims.norm == "rmsnorm":
-            return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
-        mu = jnp.mean(x, -1, keepdims=True)
-        var = jnp.mean(jnp.square(x - mu), -1, keepdims=True)
-        return (x - mu) * jax.lax.rsqrt(var + eps)
-
-    def rope(self, x, pos):
-        """x (B, S, N, H), pos (S,) -> rotated x."""
-        h = self.dims.head_dim
-        inv = 1.0 / (self.dims.rope_theta ** (jnp.arange(0, h, 2, dtype=jnp.float32) / h))
-        ang = pos.astype(jnp.float32)[:, None] * inv  # (S, H/2)
-        cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
-        x1, x2 = x[..., : h // 2], x[..., h // 2 :]
-        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-    def layer(self, x, p):
-        dims = self.dims
-        b, s, _ = x.shape
-        pos = jnp.arange(s)
-        h = self.norm(x, p.get("attn_norm"))
-        q = self.rope(self.mm("bsd,dnh->bsnh", h, p["wq"]), pos)
-        k = self.rope(self.mm("bsd,dkh->bskh", h, p["wk"]), pos)
-        v = self.mm("bsd,dkh->bskh", h, p["wv"])
-        g = dims.n_heads // dims.n_kv
-        qg = q.reshape(b, s, dims.n_kv, g, dims.head_dim)
-        scores = self.mm("bskgh,btkh->bkgst", qg, k) * dims.head_dim ** -0.5
-        causal = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
-        scores = jnp.where(causal, scores, -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1)
-        att = self.mm("bkgst,btkh->bskgh", probs, v).reshape(b, s, dims.n_heads, dims.head_dim)
-        x = x + self.mm("bsnh,nhd->bsd", att, p["wo"])
-        h = self.norm(x, p.get("mlp_norm"))
-        a = jax.nn.silu(self.mm("bsd,df->bsf", h, p["w_gate"]))
-        u = self.mm("bsd,df->bsf", h, p["w_up"])
-        return x + self.mm("bsf,fd->bsd", a * u, p["w_down"])
-
-    def hidden(self, w, tokens):
-        """tokens (B, S) -> final-normed hidden states (B, S, D), one layer
-        at a time (each layer recomputed in the backward pass)."""
-        layer_keys = [k for k in w if k not in ("embed", "final_norm")]
-        stacked = {k: w[k] for k in layer_keys}
-        x = w["embed"][tokens]
-
-        @jax.checkpoint
-        def body(x, p):
-            return self.layer(x, p), None
-
-        x, _ = jax.lax.scan(body, x, stacked)
-        return self.norm(x, w.get("final_norm"))
-
-    def logits(self, w, h):
-        return self.mm("bsd,vd->bsv", h, w["embed"])
-
-    def loss(self, w, tokens, targets, chunk: int = 256):
-        """Mean next-token cross-entropy over every position."""
-        h = self.hidden(w, tokens)
-        b, s, d = h.shape
-        c = min(chunk, s)
-        hs = jnp.moveaxis(h.reshape(b, s // c, c, d), 1, 0)
-        ts = jnp.moveaxis(targets.reshape(b, s // c, c), 1, 0)
-
-        @jax.checkpoint
-        def nll(hc, tc):
-            lg = self.logits(w, hc)
-            gold = jnp.take_along_axis(lg, tc[..., None], -1)[..., 0]
-            return jnp.sum(jax.nn.logsumexp(lg, -1) - gold)
-
-        tot, _ = jax.lax.scan(lambda t, a: (t + nll(*a), None), jnp.zeros(()), (hs, ts))
-        return tot / (b * s)
 
 
 # ---------------------------------------------------------------------------
@@ -136,7 +64,7 @@ def leaf_norms(tree: dict) -> dict:
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1), donate_argnums=(2,))
-def _train_step(ref: Reference, opt_items: tuple, state, tokens, targets):
+def _train_step(ref, opt_items: tuple, state, tokens, targets):
     opt = dict(opt_items)
     w, m, v, step = state["w"], state["m"], state["v"], state["step"] + 1
     loss, g = jax.value_and_grad(ref.loss)(w, tokens, targets)
@@ -159,7 +87,7 @@ def _train_step(ref: Reference, opt_items: tuple, state, tokens, targets):
     return new, loss, applied
 
 
-def train_readings(ref: Reference, w0: dict, batches: list, opt: dict) -> dict:
+def train_readings(ref, w0: dict, batches: list, opt: dict) -> dict:
     """Run ``len(batches)`` AdamW steps from ``w0``.
 
     Returns each step's loss, the first step's gradient as the optimizer
@@ -195,8 +123,20 @@ def train_readings(ref: Reference, w0: dict, batches: list, opt: dict) -> dict:
 # ---------------------------------------------------------------------------
 
 
+def _row_gaps(ref, ctrl, w, hr, nx, hq):
+    """Gaps of one block of rows: ``hr`` (C, D) hidden states, ``nx`` (C,)
+    the tokens that follow, ``hq`` the control's hidden states or None."""
+    lg = ref.logits(w, hr[None])[0]
+    best = jnp.max(lg, -1)
+    gap = best - jnp.take_along_axis(lg, nx[:, None], -1)[:, 0]
+    if hq is None:
+        return gap, jnp.zeros_like(gap)
+    top = jnp.argmax(ctrl.logits(w, hq[None])[0], -1)
+    return gap, best - jnp.take_along_axis(lg, top[:, None], -1)[:, 0]
+
+
 @functools.partial(jax.jit, static_argnums=(0, 1))
-def _gaps(ref: Reference, ctrl, w, tokens):
+def _gaps(ref, ctrl, w, tokens):
     """For every position t of ``tokens`` (1, T): the reference's best logit
     minus its logit of ``tokens[t + 1]``, and (with a control) minus its logit
     of the token the control puts first."""
@@ -205,24 +145,30 @@ def _gaps(ref: Reference, ctrl, w, tokens):
     t = tokens.shape[1]
     nxt = jnp.concatenate([tokens[0, 1:], tokens[0, :1]])
     c = 128 if t % 128 == 0 else t
-
-    def rows(args):
-        hr, nx, hq = args
-        lg = ref.logits(w, hr[None])[0]
-        best = jnp.max(lg, -1)
-        gap = best - jnp.take_along_axis(lg, nx[:, None], -1)[:, 0]
-        if hq is None:
-            return gap, jnp.zeros_like(gap)
-        top = jnp.argmax(ctrl.logits(w, hq[None])[0], -1)
-        return gap, best - jnp.take_along_axis(lg, top[:, None], -1)[:, 0]
-
     hs = h[0].reshape(t // c, c, -1)
     hq = hc[0].reshape(t // c, c, -1) if hc is not None else None
-    gap, cgap = jax.lax.map(rows, (hs, nxt.reshape(t // c, c), hq))
+    gap, cgap = jax.lax.map(
+        lambda a: _row_gaps(ref, ctrl, w, *a), (hs, nxt.reshape(t // c, c), hq)
+    )
     return gap.reshape(t), cgap.reshape(t)
 
 
-def served_gaps(ref: Reference, w: dict, prompt, served, width: int, ctrl=None):
+def _gaps_blocked(ref, ctrl, w, tokens):
+    """:func:`_gaps` of a ``blocked`` reference, run eagerly."""
+    h = ref.hidden(w, tokens)
+    hc = ctrl.hidden(w, tokens) if ctrl is not None else None
+    t = tokens.shape[1]
+    nxt = jnp.concatenate([tokens[0, 1:], tokens[0, :1]])
+    c = 128 if t % 128 == 0 else t
+    parts = [
+        _row_gaps(ref, ctrl, w, h[0, i : i + c], nxt[i : i + c],
+                  None if hc is None else hc[0, i : i + c])
+        for i in range(0, t, c)
+    ]
+    return jnp.concatenate([g for g, _ in parts]), jnp.concatenate([q for _, q in parts])
+
+
+def served_gaps(ref, w, prompt, served, width: int, ctrl=None):
     """Gaps of ``served`` (the tokens a program emitted after ``prompt``),
     position by position; ``width`` is the padded length compiled for."""
     import numpy as np
@@ -234,6 +180,7 @@ def served_gaps(ref: Reference, w: dict, prompt, served, width: int, ctrl=None):
         raise ValueError(f"request of {len(full)} tokens exceeds width {width}")
     seq[: len(full)] = full
     with jax.default_matmul_precision("highest"):
-        gap, cgap = _gaps(ref, ctrl, w, jax.numpy.asarray(seq[None]))
+        gaps = _gaps_blocked if getattr(ref, "blocked", False) else _gaps
+        gap, cgap = gaps(ref, ctrl, w, jax.numpy.asarray(seq[None]))
     sl = slice(p - 1, p - 1 + n)
     return np.asarray(gap)[sl], np.asarray(cgap)[sl]

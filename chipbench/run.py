@@ -4,7 +4,8 @@
     python3 chipbench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 from the root of a checkout.  The cell is built from its files by the names
-``BENCHMARK.json`` gives: ``chipbench/configs/<config>.json``,
+``BENCHMARK.json`` gives: ``chipbench/configs/<config>.json`` (which names
+its architecture, ``chipbench/archs/<arch>.py``; ``dense`` by default),
 ``chipbench/traffic/<traffic>.json`` (which names its entry and its
 placement, ``chipbench/placements/<name>.json``), one reader per metric in
 ``chipbench/metrics/<metric>.py`` and the limits of ``correct`` in
@@ -86,22 +87,22 @@ def build_cell(bench: dict, workload: str, seed: int, *, smoke: bool = False,
     from repro.configs import get_config, get_smoke_config
 
     wl = workload_entry(bench, workload)
-    dims, _ = model.load_config(wl["config"], Path(root) / "configs")
+    arch, dims, raw = model.load(wl["config"], root)
     mix = traffic.load(wl["traffic"], Path(root) / "traffic")
     placement = traffic.load_placement(mix["placement"], Path(root) / "placements")
     if smoke:
-        pcfg = get_smoke_config(dims.program_arch)
-        dims = model.smoke_dims(dims, pcfg)
+        pcfg = get_smoke_config(raw["program_arch"])
+        dims = arch.smoke(dims, pcfg)
     else:
-        pcfg = get_config(dims.program_arch)
-        model.check_program_config(dims, pcfg)
+        pcfg = get_config(raw["program_arch"])
+        arch.check_program(dims, pcfg)
     if mix["entry"] == "train":
         from chipbench.train_cell import TrainCell as Cell
     elif mix["entry"] == "serve":
         from chipbench.serve_cell import ServeCell as Cell
     else:
         raise ValueError(f"unknown entry {mix['entry']!r} in traffic {wl['traffic']!r}")
-    return Cell(dims, pcfg, mix, placement, seed)
+    return Cell(arch, dims, pcfg, mix, placement, seed)
 
 
 class _CompileCounter:
@@ -135,8 +136,10 @@ def run_cell(
     bench: dict | None = None,
     t_start: float = T_START,
     log=print,
+    root: Path = HERE,
 ) -> dict:
-    """One run of ``workload``; returns the result object."""
+    """One run of ``workload``, built from the files under ``root``; returns
+    the result object."""
     import jax
 
     bench = bench or load_benchmark()
@@ -147,7 +150,7 @@ def run_cell(
         axis_types=(jax.sharding.AxisType.Auto,) * 2,
     )
     compiles = _CompileCounter()
-    cell = build_cell(bench, workload, seed, smoke=smoke)
+    cell = build_cell(bench, workload, seed, smoke=smoke, root=root)
     cell.setup(mesh)
     for name, t in cell.phase.seconds.items():
         log(f"set-up phase {name}: {t:.3f} s")
@@ -189,7 +192,7 @@ def run_cell(
 
     metrics = {}
     for m in metrics_for(bench, workload, traced):
-        value = reader(m["name"])(run)
+        value = reader(m["name"], root)(run)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 
@@ -197,7 +200,7 @@ def run_cell(
     with span("bench.check"):
         numbers, ctrl = _compare(cell, control, log)
     log(f"check: {time.perf_counter() - t_check:.3f} s")
-    correct, checks = check.judge(numbers, check.limits(workload, HERE / "limits"))
+    correct, checks = check.judge(numbers, check.limits(workload, Path(root) / "limits"))
     device = {
         "platform": devices[0].platform, "kind": kind,
         "count": len(jax.devices()), "memory_peak_bytes": run["peak_bytes"],

@@ -8,6 +8,10 @@ back; with ``refill`` a retired slot takes the next queued request at the
 end of the same step, so the batch stays full and admissions (with their
 prefill) happen inside the window.  Every emitted token is stamped with the
 host time its step returned.
+
+The weights and the reference come from the architecture the configuration
+names; every key of the placement file goes to ``ServeSession`` under its own
+name.
 """
 from __future__ import annotations
 
@@ -38,7 +42,8 @@ def _supplied_weights(tree, host_tree):
 class ServeCell:
     kind = "serve"
 
-    def __init__(self, dims, pcfg, mix: dict, placement: dict, seed: int) -> None:
+    def __init__(self, arch, dims, pcfg, mix: dict, placement: dict, seed: int) -> None:
+        self.arch = arch
         self.dims, self.pcfg, self.mix, self.placement = dims, pcfg, mix, placement
         self.seed = seed
         self.phase = Phases()
@@ -51,24 +56,20 @@ class ServeCell:
         from repro.launch.serve import ServeSession
         from repro.train import steps as st
 
-        pl = self.placement
+        kwargs = traffic.entry_kwargs(ServeSession, self.placement)
         with self.phase("weights"):
-            wbf = weights.make(self.dims, self.seed, dtype=jax.numpy.bfloat16)
-            tree = weights.to_program(wbf, self.dims)
-            weights.check_layout(tree, st.abstract_params(self.pcfg))
-            streamed = pl["param_kind"] != "device"
-            host = jax.device_get(tree) if streamed else None
+            bf16 = jax.numpy.bfloat16
+            streamed = kwargs.get("param_kind", "device") != "device"
             if streamed:
-                del wbf, tree
-                tree = None
+                tree, host = None, self.arch.host_tree(self.dims, self.seed, bf16)
+            else:
+                tree, host = self.arch.device_tree(self.dims, self.seed, bf16), None
+            weights.check_layout(host if streamed else tree, st.abstract_params(self.pcfg))
         with self.phase("session"), _supplied_weights(tree, host):
             self.session = ServeSession(
                 self.pcfg, mesh,
                 slots=self.mix["slots"], max_len=traffic.max_len(self.mix),
-                kv_kind=pl["kv_kind"], page_len=pl["page_len"],
-                hot_pages=pl["hot_pages"], seed=self.seed,
-                param_kind=pl["param_kind"],
-                device_budget_mb=pl["device_budget_mb"],
+                seed=self.seed, **kwargs,
             )
         del tree, host
         self.stream = traffic.RequestStream(self.mix, self.dims.vocab, self.seed)
@@ -96,8 +97,6 @@ class ServeCell:
         return {"weights": self.session.param_stats, "kv": self.session.stats}
 
     def window(self, seconds: float) -> dict:
-        from chipbench import flops
-
         s = self.session
         t0 = time.perf_counter()
         n_steps, prefills, end, model_flops = 0, 0, t0, 0.0
@@ -111,7 +110,7 @@ class ServeCell:
                 break
             before = s.active
             model_flops += sum(
-                flops.decode_token(self.dims, s.pager.tables[rid].pos + 1)
+                self.arch.decode_token(self.dims, s.pager.tables[rid].pos + 1)
                 for rid in before
             )
             with span("bench.serve_step"):
@@ -121,7 +120,7 @@ class ServeCell:
             for rid in emitted:
                 if rid not in before:  # admitted at the end of this step
                     prefills += 1
-                    model_flops += flops.prefill(self.dims, len(s.requests[rid].prompt))
+                    model_flops += self.arch.prefill(self.dims, len(s.requests[rid].prompt))
             n_steps += 1
         tokens = sum(sum(1 for t in ts if t > t0) for ts in self.times.values())
         gaps = []
@@ -156,11 +155,9 @@ class ServeCell:
         """Per-token gaps of the sampled requests: ``(served, control)``."""
         import jax.numpy as jnp
 
-        wbf = weights.make(self.dims, self.seed, dtype=jnp.bfloat16)
-        w = {k: v.astype(jnp.float32) for k, v in wbf.items()}
-        del wbf
-        ref = reference.Reference(self.dims)
-        ctrl = reference.Reference(self.dims, quant=True) if quant else None
+        w = self.arch.reference_weights(self.dims, self.seed, jnp.bfloat16)
+        ref = self.arch.Reference(self.dims)
+        ctrl = self.arch.Reference(self.dims, quant=True) if quant else None
         served, control = [], []
         for rid in self.sample():
             prompt, out = self.served[rid]

@@ -12,6 +12,7 @@ change to the program cannot move the yardstick.
 from __future__ import annotations
 
 import dataclasses
+import inspect
 import json
 from pathlib import Path
 
@@ -26,6 +27,19 @@ def load(name: str, traffic_dir: Path = ROOT / "traffic") -> dict:
 
 def load_placement(name: str, placements_dir: Path = ROOT / "placements") -> dict:
     return json.loads((Path(placements_dir) / f"{name}.json").read_text())
+
+
+def entry_kwargs(entry, placement: dict) -> dict:
+    """The placement's keys as keyword arguments of ``entry`` (the program's
+    ``ServeSession`` or ``build_trainer``), each under its own name; a key
+    that ``entry`` does not take is an error, raised before anything is built."""
+    params = inspect.signature(entry).parameters
+    unknown = sorted(k for k in placement if k not in params)
+    if unknown:
+        raise ValueError(
+            f"placement keys {unknown} are not parameters of {entry.__qualname__}"
+        )
+    return dict(placement)
 
 
 # ---------------------------------------------------------------------------

@@ -6,6 +6,10 @@ and drives ``driver.step_fn`` through the first ``checked_steps`` steps
 (the first compiles).  The same step function and state then run the
 window.  What those first steps produced is read off the program's state
 as it goes, and compared with the reference once the window has closed.
+
+The weights and the reference come from the architecture the configuration
+names; every key of the placement file goes to ``build_trainer`` under its
+own name.
 """
 from __future__ import annotations
 
@@ -34,7 +38,8 @@ def _closure_object(fn, attr: str):
 class TrainCell:
     kind = "train"
 
-    def __init__(self, dims, pcfg, mix: dict, placement: dict, seed: int) -> None:
+    def __init__(self, arch, dims, pcfg, mix: dict, placement: dict, seed: int) -> None:
+        self.arch = arch
         self.dims, self.pcfg, self.mix, self.placement = dims, pcfg, mix, placement
         self.seed = seed
         self.opt = mix["optimizer"]
@@ -59,8 +64,8 @@ class TrainCell:
             weight_decay=o["weight_decay"], grad_clip=o["grad_clip"],
             min_lr_ratio=o["min_lr_ratio"],
         )
+        kwargs = traffic.entry_kwargs(build_trainer, self.placement)
         self._ckpt_dir = tempfile.TemporaryDirectory(prefix="chipbench-ckpt-")
-        budget = self.placement["device_budget_mb"]
         with self.phase("build"):
             self.driver = build_trainer(
                 self.pcfg, mesh,
@@ -70,25 +75,26 @@ class TrainCell:
                     total_steps=1 << 30, checkpoint_every=0,
                     checkpoint_dir=self._ckpt_dir.name, max_restarts=0,
                 ),
-                seed=self.seed,
-                param_kind=self.placement["param_kind"],
-                device_budget_mb=budget,
+                seed=self.seed, **kwargs,
             )
             self.streamed = _closure_object(self.driver.step_fn, "opt_stats")
             abstract = st.abstract_params(self.pcfg)
+            # the trainer's own plan, from the placement keys that set it
             self.plan = WeightStreamPlan(
-                self.pcfg, abstract, device_budget_mb=budget
+                self.pcfg, abstract,
+                layers_per_group=kwargs.get("param_layers_per_group"),
+                device_budget_mb=kwargs.get("device_budget_mb"),
+                expert_stream=kwargs.get("expert_stream", False),
             )
         with self.phase("weights"):
-            w32 = weights.make(self.dims, self.seed)
-            tree32 = weights.to_program(w32, self.dims)
+            tree32 = self.arch.device_tree(self.dims, self.seed, jnp.float32)
             weights.check_layout(tree32, abstract)
             tree16 = jax.jit(
                 lambda t: jax.tree.map(lambda x: x.astype(jnp.bfloat16), t)
             )(tree32)
             host32 = jax.device_get(tree32)
             host16 = jax.device_get(tree16)
-            del w32, tree32, tree16
+            del tree32, tree16
             # the masters start as views of ``host32``; the copy keeps the
             # initial weights whatever the program does with its homes
             self.init32 = jax.tree.map(np.copy, host32)
@@ -140,8 +146,8 @@ class TrainCell:
                 for k, tree in self.state["opt"]["groups"].items()
             }
         }
-        full = weights.from_program(self.plan.assemble(home), self.dims)
-        base = weights.from_program(minus, self.dims) if minus is not None else None
+        full = self.arch.from_program(self.plan.assemble(home), self.dims)
+        base = self.arch.from_program(minus, self.dims) if minus is not None else None
         norms, kept = {}, {}
         for k, a in full.items():
             a = np.asarray(a, np.float32)
@@ -174,9 +180,7 @@ class TrainCell:
             ends.append(time.perf_counter())
             if ends[-1] - t0 >= seconds:
                 break
-        from chipbench import flops
-
-        per_token = flops.train_per_token(self.dims, self.mix["seq_len"])
+        per_token = self.arch.train_per_token(self.dims, self.mix["seq_len"])
         tokens = self.mix["batch"] * self.mix["seq_len"] * n
         return {
             "window_s": ends[-1] - t0, "steps": n, "t0": t0,
@@ -194,12 +198,14 @@ class TrainCell:
     def reference_readings(self, quant: bool = False, half_batch: bool = False) -> dict:
         """The reference's readings; ``half_batch`` plants a fault (each
         step's loss is the mean over the first half of its rows only)."""
-        w0 = weights.make(self.dims, self.seed)
+        import jax.numpy as jnp
+
+        w0 = self.arch.reference_weights(self.dims, self.seed, jnp.float32)
         batches = [self.batch(i) for i in range(self.mix["checked_steps"])]
         if half_batch:
             half = self.mix["batch"] // 2
             batches = [(t[:half], y[:half]) for t, y in batches]
-        ref = reference.Reference(self.dims, quant=quant)
+        ref = self.arch.Reference(self.dims, quant=quant)
         return reference.train_readings(ref, w0, batches, self.opt)
 
     def program_readings(self) -> dict:
